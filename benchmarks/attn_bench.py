@@ -131,7 +131,7 @@ def run():
                 fwd_k = jax.jit(lambda qh, kh, vh: flash_attention_kernel(
                     qh, kh, vh, **kw))
                 out, lse = fwd_k(qh, kh, vh)
-                delta = jnp.sum(doh * out, axis=-1)
+                delta = jnp.sum(doh * out, axis=-1)[:, None, :]
                 dq_us = time_us(jax.jit(
                     lambda *a: flash_attention_bwd_dq(*a, **kw)),
                     qh, kh, vh, doh, lse, delta)

@@ -72,6 +72,9 @@ def _one_scenario(plan, n, dead, batch, timeout=1200):
     env = dict(os.environ)
     flag = f"--xla_force_host_platform_device_count={n}"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
+    # forced host devices are CPU devices: pin the child there so it never
+    # contends for an accelerator the parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

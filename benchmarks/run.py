@@ -125,6 +125,8 @@ def main(argv=None) -> None:
                          "decode,ssm,rollout,elastic")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (attn_bench, decode_bench, elastic_bench,
                             fig3_loss, fig4_memory, roofline_bench,
                             rollout_bench, ssm_bench, table1_comm,

@@ -57,6 +57,9 @@ def measure_plan_collectives(plans=MEASURED_PLANS, timeout=1200):
     env = dict(os.environ)
     flag = "--xla_force_host_platform_device_count=4"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
+    # the child only counts HLO collectives on forced host devices; pinned
+    # to the CPU it never contends for an accelerator the parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import grad_sync
 from repro.core.schedule import RULE_CDP_V1, RULE_CDP_V2, RULE_DP
 from repro.core.update_rules import (fresh_threshold_traced, needs_prev_params,
@@ -248,24 +247,25 @@ def make_train_step(cfg, trainer: TrainerConfig, mesh, opt: Optimizer,
             return P(*entries)
         return jax.tree.map(one, params, layout)
 
+    manual = sh.manual_axes(mesh, daxes)
+    # activation sharding constrains the model axis, legal only while auto
+    seq_parallel = trainer.seq_parallel and trainer.model_axis not in manual
+
     def train_step(state, batch):
         params = state["params"]
         params_prev = state["params_prev"] if use_prev else params
-        if trainer.seq_parallel and compat.PARTIAL_AUTO_SHARD_MAP:
-            # perf lever only: on old jax the shard_map fallback is fully
-            # manual, where an in-body sharding constraint over the model
-            # axis is illegal — skip it (numerics are unaffected)
+        if seq_parallel:
             from repro.models import blocks as blocks_mod
             blocks_mod.set_activation_sharding(mesh, trainer.model_axis)
         rep = lambda t: jax.tree.map(lambda _: P(), t)
         in_specs = (rep(params), rep(params_prev),
                     sh.batch_manual_pspecs(batch, daxes), P())
         out_specs = (grad_out_specs(params), P(), P())
-        grads, loss, metrics = compat.shard_map(
+        grads, loss, metrics = jax.shard_map(
             grad_shard, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(daxes), check_vma=False)(
+            axis_names=manual, check_vma=False)(
                 params, params_prev, batch, state["step"])
-        if trainer.seq_parallel:
+        if seq_parallel:
             from repro.models import blocks as blocks_mod
             blocks_mod.set_activation_sharding(None, None)
 

@@ -374,19 +374,19 @@ def _groups(cache) -> List[str]:
 
 def scatter_prefill(paged_cache: PyTree, dense_cache: PyTree, admit_mask):
     """Splice a dense ragged-prefilled cache [L,B,T,KV,hd] into the pool
-    through the table (rows with admit_mask False write to the trash block —
-    their live blocks and lengths are untouched). T may cover fewer logical
-    blocks than nb_max; the rest stay decode-writable. This is the
-    bitwise-exact admission path: the values written are the DENSE prefill's
-    values, so a subsequent paged decode reads exactly what the dense engine
-    would."""
+    [L,NB+1,KV,bs,hd] through the table (rows with admit_mask False write
+    to the trash block — their live blocks and lengths are untouched). T
+    may cover fewer logical blocks than nb_max; the rest stay
+    decode-writable. This is the bitwise-exact admission path: the values
+    written are the DENSE prefill's values, so a subsequent paged decode
+    reads exactly what the dense engine would."""
     import jax.numpy as jnp
     table = paged_cache["table"]
     out = dict(paged_cache)
     for g in _groups(paged_cache):
         pool_k = paged_cache[g]["k"]
         trash = pool_k.shape[1] - 1
-        bs = pool_k.shape[2]
+        bs = pool_k.shape[3]
         kd, vd = dense_cache[g]["k"], dense_cache[g]["v"]
         L, Bv, T, KV, hd = kd.shape
         nbp = T // bs
@@ -397,11 +397,13 @@ def scatter_prefill(paged_cache: PyTree, dense_cache: PyTree, admit_mask):
         # all-zero for every read path that is masked against it
         tbl = jnp.where(admit_mask[:, None] & (table[:, :nbp] != trash),
                         table[:, :nbp], trash + 1)
+
+        def blocks(x):                        # -> [L, Bv, nbp, KV, bs, hd]
+            return jnp.swapaxes(x.reshape(L, Bv, nbp, bs, KV, hd), 3, 4)
+
         out[g] = {
-            "k": pool_k.at[:, tbl].set(kd.reshape(L, Bv, nbp, bs, KV, hd),
-                                       mode="drop"),
-            "v": paged_cache[g]["v"].at[:, tbl].set(
-                vd.reshape(L, Bv, nbp, bs, KV, hd), mode="drop"),
+            "k": pool_k.at[:, tbl].set(blocks(kd), mode="drop"),
+            "v": paged_cache[g]["v"].at[:, tbl].set(blocks(vd), mode="drop"),
             "len": jnp.where(admit_mask[None, :], dense_cache[g]["len"],
                              paged_cache[g]["len"]),
         }
@@ -422,7 +424,7 @@ def copy_blocks(paged_cache: PyTree, src, dst):
 
 
 def gather_slot(paged_cache: PyTree, row_table):
-    """One slot's blocks, gathered to [L, nb, bs, KV, hd] per group (the
+    """One slot's blocks, gathered to [L, nb, KV, bs, hd] per group (the
     sleep-level-1 offload payload; unallocated entries carry trash garbage
     that ``upload_slot`` never writes back)."""
     return {g: {"k": paged_cache[g]["k"][:, row_table],
@@ -461,13 +463,13 @@ def paged_row_health(cache: PyTree):
     B, nb = table.shape
     ok = jnp.ones((B,), bool)
     for g in _groups(cache):
-        bs = cache[g]["k"].shape[2]
+        bs = cache[g]["k"].shape[3]
         ln = cache[g]["len"][0]                          # [B] (equal per layer)
         pos = jnp.arange(nb * bs).reshape(nb, bs)
         valid = pos[None] < ln[:, None, None]            # [B, nb, bs]
-        m = valid[None, :, :, :, None, None]
+        m = valid[None, :, :, None, :, None]
         for kv in ("k", "v"):
-            gathered = cache[g][kv][:, table]            # [L,B,nb,bs,KV,hd]
+            gathered = cache[g][kv][:, table]            # [L,B,nb,KV,bs,hd]
             fin = jnp.isfinite(gathered) | ~m
             ok &= jnp.all(fin, axis=(0, 2, 3, 4, 5))
     return ok

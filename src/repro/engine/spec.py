@@ -129,9 +129,8 @@ def shrink_mesh(mesh, dead_rank: int, data_axis: str = "data"):
     the dead one renumber down by one — exactly how the stage ring re-forms
     at N-1. A pod axis does not compose with elastic membership yet (the
     stage ring spans exactly the data axis)."""
+    import jax
     import numpy as np
-
-    from repro import compat
 
     names = tuple(mesh.axis_names)
     if "pod" in names:
@@ -148,4 +147,4 @@ def shrink_mesh(mesh, dead_rank: int, data_axis: str = "data"):
             f"dead rank {dead_rank} outside the {data_axis!r} axis "
             f"(size {n})")
     survivors = np.delete(np.asarray(mesh.devices), dead_rank, axis=ax)
-    return compat.mesh_from_devices(survivors, names)
+    return jax.sharding.Mesh(survivors, names)      # Auto axes by default

@@ -601,24 +601,30 @@ class TrainEngine:
 
     # -- compiled-step access ----------------------------------------------
 
-    def hlo_text(self) -> str:
-        """Optimized HLO of the compiled train step (builds if needed) —
-        feed to ``launch.roofline.parse_collectives`` to read the plan's
-        communication signature (all-reduce burst vs collective-permute
-        ring vs streamed stages) off the real program. The AOT executable
-        is kept and ``run()`` steps with it — call this BEFORE run() (the
-        demo/benchmark order) and the whole engine compiles exactly once;
-        after run() it costs one extra compile (the jit cache is not
-        shared), cached for repeat calls."""
-        if self._hlo_text is None:
+    def compile(self):
+        """The AOT-compiled train step (builds if needed): its
+        ``memory_analysis()`` says what the step holds on the device. The
+        executable is kept and ``run()`` steps with it — call this BEFORE
+        run() and the whole engine compiles exactly once; after run() it
+        costs one extra compile (the jit cache is not shared), cached for
+        repeat calls."""
+        if self._step_exec is None:
             import jax
             self.build()
-            compiled = self.step_fn.lower(self.state, self._batch0).compile()
-            self._hlo_text = compiled.as_text()
+            self._step_exec = self.step_fn.lower(self.state,
+                                                 self._batch0).compile()
             # unlike jit dispatch, the AOT executable does not auto-place
             # its inputs — commit the state to its shardings once
             self.state = jax.device_put(self.state, self.state_sh)
-            self._step_exec = compiled
+        return self._step_exec
+
+    def hlo_text(self) -> str:
+        """Optimized HLO of the compiled train step (:meth:`compile`) —
+        feed to ``launch.roofline.parse_collectives`` to read the plan's
+        communication signature (all-reduce burst vs collective-permute
+        ring vs streamed stages) off the real program."""
+        if self._hlo_text is None:
+            self._hlo_text = self.compile().as_text()
         return self._hlo_text
 
     def close(self) -> None:

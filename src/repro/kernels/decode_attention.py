@@ -15,13 +15,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
-                   *, scale: float, bk: int, nk: int, window: int):
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                   *, scale: float, bk: int, nk: int, window: int, group: int):
+    b = pl.program_id(0)
     jk = pl.program_id(1)
 
     @pl.when(jk == 0)
@@ -33,7 +32,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)                    # [1, d] row
     k = k_ref[0].astype(jnp.float32)                    # [bk, d]
     v = v_ref[0].astype(jnp.float32)                    # [bk, dv]
-    valid_len = len_ref[0]
+    valid_len = len_ref[b // group]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # [1,bk]
@@ -45,19 +44,19 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
         valid &= pos > valid_len - 1 - window
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                 # [1, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(jk == nk - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def decode_attention_kernel(q, k, v, cache_len, *, bk: int = 512,
@@ -72,28 +71,33 @@ def decode_attention_kernel(q, k, v, cache_len, *, bk: int = 512,
     scale = 1.0 / math.sqrt(d)
 
     kernel = functools.partial(_decode_kernel, scale=scale, bk=bk, nk=nk,
-                               window=window)
+                               window=window, group=group)
     q3 = q[:, None, :]                                   # [BH, 1, d]
 
-    out = pl.pallas_call(
-        kernel,
+    # cache_len rides in SMEM as a scalar-prefetch operand (a rank-1 SMEM
+    # block of one element is not a legal TPU tiling)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(BH, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, g=group: (b // g, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, j, g=group: (b // g, j, 0)),
-            pl.BlockSpec((1,), lambda b, j, g=group: (b // g,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, d), lambda b, j, ln: (b, 0, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, ln, g=group: (b // g, j, 0)),
+            pl.BlockSpec((1, bk, dv),
+                         lambda b, j, ln, g=group: (b // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, 1, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, dv), lambda b, j, ln: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((BH, 1, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k, v, cache_len)
+    )(cache_len, q3, k, v)
     return out[:, 0, :]

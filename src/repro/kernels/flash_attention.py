@@ -52,7 +52,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.tiles import col_to_row, row_to_col
 
 NEG_INF = -1e30
 
@@ -213,12 +213,12 @@ def _flash_kernel(qblk_ref, kblk_ref, meta_ref, q_ref, k_ref, v_ref,
                             preferred_element_type=jnp.float32) * scale
 
     def _accumulate(s):
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                           # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -228,8 +228,8 @@ def _flash_kernel(qblk_ref, kblk_ref, meta_ref, q_ref, k_ref, v_ref,
     @pl.when((meta & _LAST) != 0)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = col_to_row(m_ref[...] + jnp.log(l))
 
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
@@ -241,8 +241,9 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
     ``q_offset``: absolute position of q row 0 (for masking parity with
     ``models.attention.blockwise_attention``).
 
-    Returns (out [BH, Sq, dv], lse [BH, Sq] float32) — lse is the per-row
-    logsumexp residual the backward kernels consume. The grid walks only the
+    Returns (out [BH, Sq, dv], lse [BH, 1, Sq] float32) — lse is the
+    per-row logsumexp residual the backward kernels consume, kept lane-major
+    so its (1, bq) blocks satisfy the TPU tiling rule. The grid walks only the
     tiles in :func:`flash_grid_plan` (block-sparse under causal/window).
     """
     BH, Sq, d = q.shape
@@ -268,11 +269,11 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda b, t, qb, kb, mt: (b, qb[t], 0)),
-            pl.BlockSpec((1, bq), lambda b, t, qb, kb, mt: (b, qb[t])),
+            pl.BlockSpec((1, 1, bq), lambda b, t, qb, kb, mt: (b, 0, qb[t])),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
     )
@@ -281,9 +282,9 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qblk, kblk, meta, q, k, v)
@@ -308,17 +309,17 @@ def _flash_bwd_dq_kernel(qblk_ref, kblk_ref, meta_ref, q_ref, k_ref, v_ref,
     k = k_ref[0].astype(jnp.float32)                  # [bk, d]
     v = v_ref[0].astype(jnp.float32)                  # [bk, dv]
     do = do_ref[0].astype(jnp.float32)                # [bq, dv]
-    lse = lse_ref[0]                                  # [bq]
-    delta = delta_ref[0]                              # [bq]
+    lse = row_to_col(lse_ref[0])                     # [bq, 1]
+    delta = row_to_col(delta_ref[0])                 # [bq, 1]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
     def _accumulate(s):
-        p = jnp.exp(s - lse[:, None])                 # [bq, bk]
+        p = jnp.exp(s - lse)                          # [bq, bk]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale        # [bq, bk]
+        ds = p * (dp - delta) * scale                 # [bq, bk]
         dq_acc_ref[...] += jax.lax.dot(ds, k,
                                        preferred_element_type=jnp.float32)
 
@@ -334,7 +335,8 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal: bool = True,
                            window: int = 0, q_offset: int = 0, bq: int = 128,
                            bk: int = 128, group: int = 1, sk_valid: int = 0,
                            interpret: bool = False):
-    """dq of flash attention. Shapes as the forward; lse/delta: [BH, Sq] f32.
+    """dq of flash attention. Shapes as the forward; lse/delta: [BH, 1, Sq]
+    f32.
     Returns dq [BH, Sq, d] in q.dtype. Walks the same pruned tile plan as
     the forward."""
     BH, Sq, d = q.shape
@@ -349,7 +351,7 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal: bool = True,
         q_offset=q_offset, bq=bq, bk=bk, sk=sk_valid or Sk)
 
     qmap = lambda b, t, qb, kb, mt: (b, qb[t], 0)
-    qmap2 = lambda b, t, qb, kb, mt: (b, qb[t])
+    qmap2 = lambda b, t, qb, kb, mt: (b, 0, qb[t])
     kmap = lambda b, t, qb, kb, mt, g=group: (b // g, kb[t], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -360,8 +362,8 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal: bool = True,
             pl.BlockSpec((1, bk, d), kmap),
             pl.BlockSpec((1, bk, dv), kmap),
             pl.BlockSpec((1, bq, dv), qmap),
-            pl.BlockSpec((1, bq), qmap2),
-            pl.BlockSpec((1, bq), qmap2),
+            pl.BlockSpec((1, 1, bq), qmap2),
+            pl.BlockSpec((1, 1, bq), qmap2),
         ],
         out_specs=pl.BlockSpec((1, bq, d), qmap),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -370,7 +372,7 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal: bool = True,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qblk, kblk, meta, q, k, v, do, lse, delta)
@@ -398,21 +400,21 @@ def _flash_bwd_dkv_kernel(kblk_ref, qblk_ref, meta_ref, q_ref, k_ref, v_ref,
     k = k_ref[0].astype(jnp.float32)                  # [bk, d]
     v = v_ref[0].astype(jnp.float32)                  # [bk, dv]
     do = do_ref[0].astype(jnp.float32)                # [bq, dv]
-    lse = lse_ref[0]                                  # [bq]
-    delta = delta_ref[0]                              # [bq]
+    lse = row_to_col(lse_ref[0])                     # [bq, 1]
+    delta = row_to_col(delta_ref[0])                 # [bq, 1]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
     def _accumulate(s):
-        p = jnp.exp(s - lse[:, None])                 # [bq, bk]
+        p = jnp.exp(s - lse)                          # [bq, bk]
         # dv += p^T @ dO
         dv_acc_ref[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale        # [bq, bk]
+        ds = p * (dp - delta) * scale                 # [bq, bk]
         # dk += ds^T @ q
         dk_acc_ref[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
@@ -449,7 +451,7 @@ def flash_attention_bwd_dkv(q, k, v, do, lse, delta, *, causal: bool = True,
         q_offset=q_offset, bq=bq, bk=bk, ng=group, sk=sk_valid or Sk)
 
     qmap = lambda b, t, g, kb, qb, mt, G=group: (b * G + g, qb[t], 0)
-    qmap2 = lambda b, t, g, kb, qb, mt, G=group: (b * G + g, qb[t])
+    qmap2 = lambda b, t, g, kb, qb, mt, G=group: (b * G + g, 0, qb[t])
     kmap = lambda b, t, g, kb, qb, mt: (b, kb[t], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -460,8 +462,8 @@ def flash_attention_bwd_dkv(q, k, v, do, lse, delta, *, causal: bool = True,
             pl.BlockSpec((1, bk, d), kmap),
             pl.BlockSpec((1, bk, dv), kmap),
             pl.BlockSpec((1, bq, dv), qmap),
-            pl.BlockSpec((1, bq), qmap2),
-            pl.BlockSpec((1, bq), qmap2),
+            pl.BlockSpec((1, 1, bq), qmap2),
+            pl.BlockSpec((1, 1, bq), qmap2),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), kmap),
@@ -479,7 +481,7 @@ def flash_attention_bwd_dkv(q, k, v, do, lse, delta, *, causal: bool = True,
             jax.ShapeDtypeStruct((BKV, Sk, d), k.dtype),
             jax.ShapeDtypeStruct((BKV, Sk, dv), v.dtype),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(kblk2, qblk2, meta2, q, k, v, do, lse, delta)

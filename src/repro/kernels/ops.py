@@ -80,7 +80,8 @@ def _flash_core_bwd(causal, window, q_offset, bq, bk, group, sk_valid,
                     interpret, res, do):
     qh, kh, vh, out, lse = res
     # delta pass: D_i = sum_d dO_id * O_id, one fused elementwise-reduce
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, None, :]                  # [BH, 1, Sq]
     kw = dict(causal=causal, window=window, q_offset=q_offset, bq=bq, bk=bk,
               group=group, sk_valid=sk_valid, interpret=interpret)
     dq = flash_attention_bwd_dq(qh, kh, vh, do, lse, delta, **kw)
@@ -151,7 +152,7 @@ def decode_attention(q, k, v, cache_len, *, window: int = 0, bk: int = 512,
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pool, v_pool, table, cache_len, *,
                            interpret: bool = False):
-    """q: [B,1,H,dh]; k/v_pool: [NB+1,bs,KV,dh] (block pool, last block is
+    """q: [B,1,H,dh]; k/v_pool: [NB+1,KV,bs,dh] (block pool, last block is
     the trash block); table: [B,nb] int32; cache_len: [B] -> [B,1,H,dv].
     The ``paged_attn="pallas"`` decode op: KV blocks are read through the
     scalar-prefetched block table, no gather materialises the row's cache."""

@@ -1,8 +1,10 @@
 """Pallas TPU attention over a paged KV cache: block-table-indexed reads.
 
 The serving engine's paged cache stores KV in a fixed pool of ``bs``-token
-blocks, ``k/v: [NB+1, bs, KV, dh]`` (the last block is a write-off "trash"
-block that absorbs masked writes and backs unallocated table entries), with
+blocks, ``k/v: [NB+1, KV, bs, dh]`` (the last block is a write-off "trash"
+block that absorbs masked writes and backs unallocated table entries; kv
+heads sit ahead of the block's tokens so one head's block is a contiguous
+``(bs, dh)`` tile, the layout the TPU's block tiling accepts), with
 a per-row block table ``table: [B, nb]`` mapping logical block j of row b to
 a physical pool slot.
 
@@ -30,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -52,8 +52,8 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)                     # [1, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bs, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # [bs, dv]
+    k = k_ref[0, 0].astype(jnp.float32)                  # [bs, d]
+    v = v_ref[0, 0].astype(jnp.float32)                  # [bs, dv]
     valid_len = len_ref[b // heads]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -63,30 +63,32 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     s = jnp.where(valid, s, NEG_INF)
     # zero V outside validity: pool blocks can hold garbage (even NaN, from
     # quarantined rows) and 0 * NaN = NaN would leak through masked lanes
-    v = jnp.where(valid.reshape(bs, 1), v, 0.0)
+    col_valid = (jk * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+                 ) < valid_len
+    v = jnp.where(col_valid, v, 0.0)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(jk == nb - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, table, cache_len, *,
                                   heads: int, interpret: bool = False):
-    """q: [B*H, d]; k/v_pool: [NB+1, bs, KV, dh]; table: [B*nb] int32
+    """q: [B*H, d]; k/v_pool: [NB+1, KV, bs, dh]; table: [B*nb] int32
     (flattened [B, nb], unallocated entries point at the trash block NB);
     cache_len: [B] int32 -> [B*H, dv]."""
     BH, d = q.shape
-    _, bs, KV, dv = v_pool.shape
+    _, KV, bs, dv = v_pool.shape
     B = cache_len.shape[0]
     nb = table.shape[0] // B
     g = (BH // B) // KV if KV else 1          # query heads per kv head
@@ -102,17 +104,17 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, cache_len, *,
         grid=(BH, nb),
         in_specs=[
             pl.BlockSpec((1, 1, d), lambda b, j, tbl, ln: (b, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda b, j, tbl, ln: (tbl[(b // H) * nb + j], 0,
-                                                (b % H) // g, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
-                         lambda b, j, tbl, ln: (tbl[(b // H) * nb + j], 0,
-                                                (b % H) // g, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda b, j, tbl, ln: (tbl[(b // H) * nb + j],
+                                                (b % H) // g, 0, 0)),
+            pl.BlockSpec((1, 1, bs, dv),
+                         lambda b, j, tbl, ln: (tbl[(b // H) * nb + j],
+                                                (b % H) // g, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, dv), lambda b, j, tbl, ln: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
     )
@@ -120,7 +122,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, cache_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, 1, dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, cache_len, q3, k_pool, v_pool)
@@ -146,8 +148,8 @@ def _paged_prefill_kernel(tbl_ref, qs_ref, kl_ref, q_ref, k_ref, v_ref,
 
     row = b // heads
     q = q_ref[0].astype(jnp.float32)                     # [bq, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bs, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # [bs, dv]
+    k = k_ref[0, 0].astype(jnp.float32)                  # [bs, d]
+    v = v_ref[0, 0].astype(jnp.float32)                  # [bs, dv]
     q_start = qs_ref[row]
     kv_len = kl_ref[row]
 
@@ -164,18 +166,18 @@ def _paged_prefill_kernel(tbl_ref, qs_ref, kl_ref, q_ref, k_ref, v_ref,
     v = jnp.where(col_valid, v, 0.0)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(jk == nb - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_prefill_attention_kernel(q, k_pool, v_pool, table, q_start, kv_len,
@@ -185,7 +187,7 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, table, q_start, kv_len,
     position ``q_start[b//H] + i``); pools/table as in the decode kernel;
     kv_len: [B] total valid cache length per row -> [B*H, Sq, dv]."""
     BH, Sq, d = q.shape
-    _, bs, KV, dv = v_pool.shape
+    _, KV, bs, dv = v_pool.shape
     B = q_start.shape[0]
     nb = table.shape[0] // B
     H = heads
@@ -203,18 +205,18 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, table, q_start, kv_len,
         in_specs=[
             pl.BlockSpec((1, bq, d),
                          lambda b, i, j, tbl, qs, kl: (b, i, 0)),
-            pl.BlockSpec((1, bs, 1, d),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda b, i, j, tbl, qs, kl: (tbl[(b // H) * nb + j],
-                                                       0, (b % H) // g, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
+                                                       (b % H) // g, 0, 0)),
+            pl.BlockSpec((1, 1, bs, dv),
                          lambda b, i, j, tbl, qs, kl: (tbl[(b // H) * nb + j],
-                                                       0, (b % H) // g, 0)),
+                                                       (b % H) // g, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, dv),
                                lambda b, i, j, tbl, qs, kl: (b, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
     )
@@ -222,7 +224,7 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, table, q_start, kv_len,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(table, q_start, kv_len, q, k_pool, v_pool)

@@ -1,7 +1,12 @@
 """Pallas TPU chunked gated-linear-attention scan (Mamba2 SSD / mLSTM core)
 — forward AND fused one-pass backward.
 
-Layout: q,k [BH, S, dk]; v [BH, S, dv]; g [BH, S] (log-decay <= 0).
+Layout: q,k [BH, S, dk]; v [BH, S, dv]; g [BH, S] (log-decay <= 0). Inside
+the pallas_call g (and dg) travel as [BH, S, 1] columns: a (chunk,) block of
+a [BH, S] array breaks the TPU's (8, 128) tiling rule for chunk < 128.
+Per-row quantities (g, its cumsum, the decay weights) are [chunk, 1] columns
+in-kernel; prefix/suffix sums are masked [chunk, chunk] reductions, and
+row <-> column moves use the exact diagonal trick of ``kernels.tiles``.
 
 Forward — grid (BH, nchunks) with the chunk axis sequential: the [dk, dv]
 recurrent state lives in VMEM scratch and is carried across chunk
@@ -23,7 +28,7 @@ term, and all four gradients come out in a single pass:
     dk_j = (dSc^T q)_j + w_j * (v_j @ D^T)
     dv_j = (A^T dy)_j  + w_j * (k_j @ D)           A = (q k^T) . dmat
     dg   = reverse-cumsum of  q.dq - k.dk          (suffix carried across
-                                                    chunks in SMEM scratch)
+                                                    chunks in scratch)
 
 where e = exp(cumsum g), w = exp(cum[-1] - cum), P is the chunk's entering
 state and the dg identity dL/dG_t = q_t.dq_t - k_t.dk_t (G = global cumsum
@@ -39,31 +44,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.tiles import col_to_row
+
+
+def _ij(chunk: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
 
 
 def _chunk_decays(g_raw, rows_valid):
-    """(cum, e, a, w) of one chunk with padded rows masked out of the state
-    path: g forced to 0 (decay 1) and w forced to 0 (no kv contribution)."""
+    """(cum, cum_row, e, a, w) of one chunk — [Q, 1] columns except the
+    [1, Q] ``cum_row`` and the [1, 1] ``a`` — with padded rows masked out of
+    the state path: g forced to 0 (decay 1) and w forced to 0 (no kv
+    contribution)."""
+    chunk = g_raw.shape[0]
+    ii, jj = _ij(chunk)
     g = jnp.where(rows_valid, g_raw.astype(jnp.float32), 0.0)
-    cum = jnp.cumsum(g)                       # inclusive
+    cum = jnp.sum(jnp.where(jj <= ii, col_to_row(g), 0.0), axis=1,
+                  keepdims=True)              # inclusive prefix sum
+    last = cum[chunk - 1:chunk]               # [1, 1]
     e = jnp.exp(cum)
-    a = jnp.exp(cum[-1])
-    w = jnp.where(rows_valid, jnp.exp(cum[-1] - cum), 0.0)
-    return cum, e, a, w
+    a = jnp.exp(last)
+    w = jnp.where(rows_valid, jnp.exp(last - cum), 0.0)
+    return cum, col_to_row(cum), e, a, w
 
 
 def _rows_valid(chunk_id, chunk: int, s_valid: int):
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)[:, 0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
     return chunk_id * chunk + rows < s_valid
 
 
-def _intra_decay(cum, chunk: int):
+def _intra_decay(cum, cum_row):
     """[Q, Q] lower-triangular decay matrix exp(cum_i - cum_j), j <= i."""
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    return jnp.exp(jnp.where(jj <= ii, cum[:, None] - cum[None, :],
-                             -jnp.inf))
+    ii, jj = _ij(cum.shape[0])
+    return jnp.exp(jnp.where(jj <= ii, cum - cum_row, -jnp.inf))
 
 
 # ---------------------------------------------------------------------------
@@ -85,21 +99,21 @@ def _gla_fwd_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, fin_ref, *rest,
     q = q_ref[0].astype(jnp.float32)          # [Q, dk]
     k = k_ref[0].astype(jnp.float32)          # [Q, dk]
     v = v_ref[0].astype(jnp.float32)          # [Q, dv]
-    cum, e, a, w = _chunk_decays(g_ref[0], _rows_valid(c, chunk, s_valid))
+    cum, cum_row, e, a, w = _chunk_decays(g_ref[0],
+                                          _rows_valid(c, chunk, s_valid))
 
     # intra-chunk: A_ij = (q_i . k_j) * exp(cum_i - cum_j), j <= i
     scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    y = jax.lax.dot(scores * _intra_decay(cum, chunk), v,
+    y = jax.lax.dot(scores * _intra_decay(cum, cum_row), v,
                     preferred_element_type=jnp.float32)
 
     # carried-state contribution and state update
     s0 = state_ref[...]                       # [dk, dv]
     if collect:
         states_ref[0, 0] = s0                 # checkpoint: state entering c
-    y = y + jax.lax.dot(q * e[:, None], s0,
-                        preferred_element_type=jnp.float32)
-    s_local = jax.lax.dot_general(k * w[:, None], v,
+    y = y + jax.lax.dot(q * e, s0, preferred_element_type=jnp.float32)
+    s_local = jax.lax.dot_general(k * w, v,
                                   (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     state_ref[...] = a * s0 + s_local
@@ -115,9 +129,9 @@ def gla_scan_kernel(q, k, v, g, *, chunk: int = 64, s_valid: int = 0,
     """Forward chunk scan. S must be a multiple of chunk (ops.py pads);
     ``s_valid`` is the true length — padded rows never touch the state.
 
-    Returns (y [BH, S, dv], final_state [BH, dk, dv] f32), plus the
-    per-chunk entering states [BH, nc, dk, dv] f32 in the middle when
-    ``collect_states`` (the backward's residual):
+    g: [BH, S]. Returns (y [BH, S, dv], final_state [BH, dk, dv] f32),
+    plus the per-chunk entering states [BH, nc, dk, dv] f32 in the middle
+    when ``collect_states`` (the backward's residual):
     (y, states, final_state)."""
     BH, S, dk = q.shape
     dv = v.shape[-1]
@@ -146,15 +160,15 @@ def gla_scan_kernel(q, k, v, g, *, chunk: int = 64, s_valid: int = 0,
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
+            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, g)
+    )(q, k, v, g[..., None])
     y, fin = outs[0], outs[1]
     return (y, outs[2], fin) if collect_states else (y, fin)
 
@@ -172,15 +186,15 @@ def _gla_bwd_kernel(q_ref, k_ref, v_ref, g_ref, st_ref, dy_ref,
     @pl.when(r == 0)
     def _init():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
-        carry_ref[0] = 0.0
+        carry_ref[...] = jnp.zeros_like(carry_ref)
 
     q = q_ref[0].astype(jnp.float32)          # [Q, dk]
     k = k_ref[0].astype(jnp.float32)          # [Q, dk]
     v = v_ref[0].astype(jnp.float32)          # [Q, dv]
     dy = dy_ref[0].astype(jnp.float32)        # [Q, dv]
     rows_valid = _rows_valid(c, chunk, s_valid)
-    cum, e, a, w = _chunk_decays(g_ref[0], rows_valid)
-    dmat = _intra_decay(cum, chunk)
+    cum, cum_row, e, a, w = _chunk_decays(g_ref[0], rows_valid)
+    dmat = _intra_decay(cum, cum_row)
 
     P = st_ref[0, 0]                          # state entering this chunk
     D = dstate_ref[...]                       # adjoint of the LEAVING state
@@ -191,28 +205,32 @@ def _gla_bwd_kernel(q_ref, k_ref, v_ref, g_ref, st_ref, dy_ref,
                               preferred_element_type=jnp.float32) * dmat
 
     dq = jax.lax.dot(dsc, k, preferred_element_type=jnp.float32) + \
-        e[:, None] * jax.lax.dot_general(dy, P, (((1,), (1,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        e * jax.lax.dot_general(dy, P, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
     dk = jax.lax.dot_general(dsc, q, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32) + \
-        w[:, None] * jax.lax.dot_general(v, D, (((1,), (1,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        w * jax.lax.dot_general(v, D, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
     dv = jax.lax.dot_general(scores * dmat, dy, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32) + \
-        w[:, None] * jax.lax.dot(k, D, preferred_element_type=jnp.float32)
+        w * jax.lax.dot(k, D, preferred_element_type=jnp.float32)
 
     # decay gradient: dL/dG_t = q_t.dq_t - k_t.dk_t, dg = suffix-sum of dG
-    # (within-chunk reverse cumsum + the cross-chunk suffix carried in SMEM)
+    # (within-chunk masked suffix sum + the cross-chunk suffix carried in a
+    # [1, 1] scratch)
     dG = jnp.where(rows_valid,
-                   jnp.sum(q * dq, axis=-1) - jnp.sum(k * dk, axis=-1), 0.0)
-    tot = jnp.sum(dG)
-    carry = carry_ref[0]
-    dg = carry + (tot - jnp.cumsum(dG) + dG)
-    carry_ref[0] = carry + tot
+                   jnp.sum(q * dq, axis=-1, keepdims=True) -
+                   jnp.sum(k * dk, axis=-1, keepdims=True), 0.0)   # [Q, 1]
+    ii, jj = _ij(chunk)
+    suffix = jnp.sum(jnp.where(jj >= ii, col_to_row(dG), 0.0), axis=1,
+                     keepdims=True)
+    carry = carry_ref[...]
+    dg = carry + suffix
+    carry_ref[...] = carry + jnp.sum(dG, axis=0, keepdims=True)
 
     # adjoint state entering this chunk, for the next (earlier) iteration
     dstate_ref[...] = a * D + jax.lax.dot_general(
-        q * e[:, None], dy, (((0,), (0,)), ((), ())),
+        q * e, dy, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     dq_ref[0] = dq.astype(dq_ref.dtype)
@@ -233,16 +251,15 @@ def gla_scan_bwd_kernel(q, k, v, g, states, dy, *, chunk: int = 64,
     kernel = functools.partial(_gla_bwd_kernel, chunk=chunk, nc=nc,
                                s_valid=s_valid or S)
     rev = lambda b, r: (b, nc - 1 - r, 0)
-    rev_g = lambda b, r: (b, nc - 1 - r)
 
-    return pl.pallas_call(
+    dq, dk, dv, dg = pl.pallas_call(
         kernel,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), rev),
             pl.BlockSpec((1, chunk, dk), rev),
             pl.BlockSpec((1, chunk, dv), rev),
-            pl.BlockSpec((1, chunk), rev_g),
+            pl.BlockSpec((1, chunk, 1), rev),
             pl.BlockSpec((1, 1, dk, dv), lambda b, r: (b, nc - 1 - r, 0, 0)),
             pl.BlockSpec((1, chunk, dv), rev),
         ],
@@ -250,17 +267,18 @@ def gla_scan_bwd_kernel(q, k, v, g, states, dy, *, chunk: int = 64,
             pl.BlockSpec((1, chunk, dk), rev),
             pl.BlockSpec((1, chunk, dk), rev),
             pl.BlockSpec((1, chunk, dv), rev),
-            pl.BlockSpec((1, chunk), rev_g),
+            pl.BlockSpec((1, chunk, 1), rev),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct(g.shape, g.dtype),
+            jax.ShapeDtypeStruct(g.shape + (1,), g.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32),
-                        pltpu.SMEM((1,), jnp.float32)],
-        compiler_params=CompilerParams(
+                        pltpu.VMEM((1, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, g, states, dy)
+    )(q, k, v, g[..., None], states, dy)
+    return dq, dk, dv, dg[..., 0]

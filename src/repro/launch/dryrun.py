@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"     # the 512 devices are host devices
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)
 combination against ShapeDtypeStruct inputs — proving the sharding config is

@@ -6,7 +6,14 @@ touches jax device state. Target: TPU v5e pods — 256 chips per pod as a
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto (GSPMD) axis types on every axis — the
+    step functions rely on sharding propagation, which ``jax.make_mesh``'s
+    default Explicit axes would replace with sharding-in-types."""
+    import jax
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

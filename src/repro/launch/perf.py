@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"     # the 512 devices are host devices
 
 """§Perf hillclimb runner: evaluate named optimisation variants of one
 (arch x shape) pair and report the roofline-term deltas vs the

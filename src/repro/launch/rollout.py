@@ -67,6 +67,8 @@ def main(argv=None):
                    host_devices=args.host_devices, seed=args.seed)
     spec = spec.auto_host_devices()     # CPU container: default to mesh size
     spec.ensure_host_devices()          # before anything imports jax state
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.engine import RolloutEngine
     engine = RolloutEngine(spec, plan=args.plan,

@@ -44,6 +44,7 @@ def main(argv=None):
 
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"          # dryrun compiles on host devices
 
     for arch in archs:
         for shape in shapes:

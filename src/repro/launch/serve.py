@@ -136,6 +136,8 @@ def main(argv=None):
                    host_devices=args.host_devices, seed=args.seed)
     spec = spec.auto_host_devices()     # CPU container: default to mesh size
     spec.ensure_host_devices()          # before anything imports jax state
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.paged and not args.max_slots:
         print("--paged requires --max-slots (continuous batching)",

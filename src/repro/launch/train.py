@@ -102,6 +102,8 @@ def main(argv=None):
                    seed=args.seed)
     spec = spec.auto_host_devices()     # CPU container: default to mesh size
     spec.ensure_host_devices()          # before anything imports jax state
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.engine import TrainEngine
     engine = TrainEngine(spec, steps=args.steps,

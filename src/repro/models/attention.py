@@ -433,14 +433,15 @@ def gqa_cache_init(cfg, batch: int, cache_len: int, dtype):
 
 # ---------------------------------------------------------------------------
 # Paged GQA: the KV cache is a pool of fixed-size blocks shared by all rows,
-# k/v [NB+1, bs, KV, hd] (block NB is the write-off "trash" block), plus a
+# k/v [NB+1, KV, bs, hd] (block NB is the write-off "trash" block), plus a
 # per-row block table [B, nb] owned by the engine. Logical cache position p of
-# row b lives at pool slot (table[b, p // bs], p % bs). Masked/out-of-range
-# writes are redirected to the trash block, and every read path zeroes V
-# outside validity (pool blocks may hold garbage, even NaN, from freed or
-# quarantined rows — 0 * NaN would leak through the exactly-zero masked
-# probabilities). Valid lanes are untouched, which is what keeps the paged
-# path bitwise-identical to the dense cache.
+# row b, kv head h lives at pool slot (table[b, p // bs], h, p % bs): each
+# head's block is one contiguous (bs, hd) tile, as the Pallas kernels read
+# it. Masked/out-of-range writes are redirected to the trash block, and
+# every read path zeroes V outside validity (pool blocks may hold garbage,
+# even NaN, from freed or quarantined rows — 0 * NaN would leak through the
+# exactly-zero masked probabilities). Valid lanes are untouched, which is
+# what keeps the paged path bitwise-identical to the dense cache.
 # ---------------------------------------------------------------------------
 
 def paged_gqa_cache_init(cfg, batch: int, num_blocks: int, block_size: int,
@@ -454,18 +455,18 @@ def paged_gqa_cache_init(cfg, batch: int, num_blocks: int, block_size: int,
     # deterministic failure (tests/test_paged_cache.py uses it as a canary)
     import os
     fill = float("nan") if os.environ.get("PAGED_POISON") else 0.0
-    return {"k": jnp.full((num_blocks + 1, block_size, KV, hd), fill, dtype),
-            "v": jnp.full((num_blocks + 1, block_size, KV, hd), fill, dtype),
+    return {"k": jnp.full((num_blocks + 1, KV, block_size, hd), fill, dtype),
+            "v": jnp.full((num_blocks + 1, KV, block_size, hd), fill, dtype),
             "len": jnp.zeros((batch,), jnp.int32)}
 
 
 def _paged_gather(pool, table):
-    """[NB+1, bs, KV, hd] gathered to the row-major logical layout
+    """[NB+1, KV, bs, hd] gathered to the row-major logical layout
     [B, nb*bs, KV, hd] through the [B, nb] block table."""
     B, nb = table.shape
-    bs = pool.shape[1]
-    g = pool[table]                                  # [B, nb, bs, KV, hd]
-    return g.reshape(B, nb * bs, g.shape[-2], g.shape[-1])
+    _, KV, bs, hd = pool.shape
+    g = jnp.swapaxes(pool[table], 2, 3)              # [B, nb, bs, KV, hd]
+    return g.reshape(B, nb * bs, KV, hd)
 
 
 def paged_prefill_attention_ref(q, k_cache, v_cache, q_start, kv_len, *,
@@ -507,7 +508,7 @@ def gqa_prefill_paged(p, cfg, x, cache, table, lengths, hist):
     k = apply_rope(k, pos, cfg.rope_theta, cfg.partial_rotary_factor,
                    interleaved=cfg.rope_2d)
     nb = table.shape[1]
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[2]
     trash = cache["k"].shape[0] - 1
     valid = jnp.arange(S)[None, :] < (lengths - hist)[:, None]
     lb = jnp.clip(pos // bs, 0, nb - 1)
@@ -517,10 +518,10 @@ def gqa_prefill_paged(p, cfg, x, cache, table, lengths, hist):
     # which stays all-zero so nothing nondeterministic can ever be read back
     phys = jnp.where(valid & (phys != trash), phys, trash + 1)
     off = pos % bs
-    k_pool = cache["k"].at[phys, off].set(k.astype(cache["k"].dtype),
-                                          mode="drop")
-    v_pool = cache["v"].at[phys, off].set(v.astype(cache["v"].dtype),
-                                          mode="drop")
+    k_pool = cache["k"].at[phys, :, off].set(
+        k.astype(cache["k"].dtype), mode="drop")
+    v_pool = cache["v"].at[phys, :, off].set(
+        v.astype(cache["v"].dtype), mode="drop")
     new_len = lengths.astype(jnp.int32)
     if registry.backend_for(cfg, "paged_attn") == "pallas":
         from repro.kernels import ops
@@ -551,7 +552,7 @@ def gqa_decode_paged(p, cfg, x, cache, table, active=None):
     k = apply_rope(k, pos, cfg.rope_theta, cfg.partial_rotary_factor,
                    interleaved=cfg.rope_2d)
     nb = table.shape[1]
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[2]
     trash = cache["k"].shape[0] - 1
     lb = jnp.clip(cache["len"] // bs, 0, nb - 1)
     phys = jnp.take_along_axis(table, lb[:, None], axis=1)[:, 0]  # [B]
@@ -561,10 +562,10 @@ def gqa_decode_paged(p, cfg, x, cache, table, active=None):
     if active is not None:
         phys = jnp.where(active, phys, trash + 1)
     off = cache["len"] % bs
-    k_pool = cache["k"].at[phys, off].set(k[:, 0].astype(cache["k"].dtype),
-                                          mode="drop")
-    v_pool = cache["v"].at[phys, off].set(v[:, 0].astype(cache["v"].dtype),
-                                          mode="drop")
+    k_pool = cache["k"].at[phys, :, off].set(
+        k[:, 0].astype(cache["k"].dtype), mode="drop")
+    v_pool = cache["v"].at[phys, :, off].set(
+        v[:, 0].astype(cache["v"].dtype), mode="drop")
     new_len = cache["len"] + (jnp.int32(1) if active is None
                               else active.astype(jnp.int32))
     if registry.backend_for(cfg, "paged_attn") == "pallas":

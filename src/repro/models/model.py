@@ -447,7 +447,7 @@ def paged_unsupported_reason(cfg: ModelConfig) -> Optional[str]:
 def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
                      block_size: int, cache_len: int) -> PyTree:
     """Paged decode cache: per layer-group block pools (k/v
-    [L, num_blocks+1, bs, KV, hd] — one physical block id spans all layers;
+    [L, num_blocks+1, KV, bs, hd] — one physical block id spans all layers;
     the extra last block is the write-off "trash" block) plus a top-level
     ``table`` [B, nb_max] int32 owned by the engine's allocator. Unallocated
     table entries point at the trash block. ``cache_len`` (a multiple of

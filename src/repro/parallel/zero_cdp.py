@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.grad_sync import _ring_perm
 from repro.core.schedule import RULE_CDP_V1
 from repro.core.update_rules import needs_prev_params
@@ -328,11 +327,12 @@ def make_train_step(cfg, trainer, plan: ParallelPlan, mesh, opt,
     def train_step(state, batch):
         chunks = state["params"]["stages"]
         src = state["params_prev"]["stages"] if use_prev else chunks
-        grads, loss, metrics = compat.shard_map(
+        grads, loss, metrics = jax.shard_map(
             grad_shard, mesh=mesh,
             in_specs=(P(axis, None), sh.batch_manual_pspecs(batch, (axis,))),
             out_specs=(P(axis, None), P(), P()),
-            axis_names={axis}, check_vma=False)(src, batch)
+            axis_names=sh.manual_axes(mesh, (axis,)),
+            check_vma=False)(src, batch)
         if trainer.grad_clip:
             gnorm = jnp.sqrt(jnp.sum(grads.astype(jnp.float32) ** 2))
             scale = jnp.minimum(1.0, trainer.grad_clip / (gnorm + 1e-9))

@@ -157,6 +157,15 @@ def batch_sharding(batch: PyTree, mesh, data_axes=("data",)) -> PyTree:
     return jax.tree.map(shard_one, batch)
 
 
+def manual_axes(mesh, data_axes=("data",)) -> set:
+    """The axes a step's shard_map is manual over: the data axes, plus every
+    axis of size 1. A Pallas (Mosaic) kernel lowers only where no mesh axis
+    is left to automatic partitioning, and a size-1 axis partitions
+    nothing — so on a data-only or one-chip mesh the kernels run inside the
+    step, while a model axis of size > 1 stays automatic (GSPMD)."""
+    return set(data_axes) | {a for a in mesh.axis_names if mesh.shape[a] == 1}
+
+
 def batch_manual_pspecs(batch: PyTree, data_axes=("data",)) -> PyTree:
     """Per-leaf specs for a batch entering a shard_map manual over the data
     axes: leading dim sharded, scalars replicated (shared by the tree-layout

@@ -5,11 +5,11 @@ import pytest
 
 def test_ring_all_reduce_equals_pmean(subproc):
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.grad_sync import ring_all_reduce, ring_all_reduce_vec, psum_all_reduce, reduce_scatter_ring
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 n = 4
 tree = {"a": jnp.arange(24.0).reshape(4, 6), "b": jnp.ones((5,)), "w": jnp.arange(32.0).reshape(8, 4)}
 pspecs = {"a": P(), "b": P(), "w": P(None, "model")}
@@ -21,7 +21,7 @@ def f(x):
     ps = psum_all_reduce(local, "data")
     return ring, ps
 
-g = compat_shard_map(f, mesh=mesh, in_specs=(jax.tree.map(lambda _: P(), tree),),
+g = jax.shard_map(f, mesh=mesh, in_specs=(jax.tree.map(lambda _: P(), tree),),
                   out_specs=(jax.tree.map(lambda _: P(), tree),)*2,
                   axis_names={"data"}, check_vma=False)
 ring, ps = jax.jit(g)(tree)
@@ -31,7 +31,7 @@ for k in tree:
 def fv(v):
     i = jax.lax.axis_index("data")
     return ring_all_reduce_vec(v * (i + 1).astype(v.dtype), "data", n)
-gv = compat_shard_map(fv, mesh=mesh, in_specs=(P(),), out_specs=P(), axis_names={"data"}, check_vma=False)
+gv = jax.shard_map(fv, mesh=mesh, in_specs=(P(),), out_specs=P(), axis_names={"data"}, check_vma=False)
 v = jnp.arange(37.0)
 np.testing.assert_allclose(np.asarray(jax.jit(gv)(v)), np.asarray(v) * 10, rtol=1e-6)
 print("RING OK")
@@ -42,7 +42,7 @@ def test_trainer_rules_semantics_on_mesh(subproc):
     """CDP-v1 must equal manual delayed-SGD; DP must equal plain SGD; v2 must
     sit between. Verified against the single-process delay simulator."""
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
@@ -50,7 +50,7 @@ from repro.core.delay_sim import make_sim_step, init_sim_state
 from repro.models import init_params, loss_fn as model_loss
 from repro.models.model import param_stage_ids
 from repro.optim import sgd_momentum
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_reduced("stablelm-1.6b")
 key = jax.random.PRNGKey(0)
 params = init_params(cfg, key)
@@ -80,14 +80,14 @@ for rule in ("dp", "cdp_v1", "cdp_v2"):
 
 def test_cdp_loss_decreases_all_rules(subproc):
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
 from repro.data import make_lm_data, lm_batch_iterator
 from repro.models import init_params
 from repro.optim import sgd_momentum
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_reduced("qwen2.5-14b")
 params = init_params(cfg, jax.random.PRNGKey(0))
 opt = sgd_momentum(0.9)
@@ -115,12 +115,12 @@ def test_zero_cdp_streaming_equals_baseline(subproc):
     """ZeRO-CDP parameter streaming (ppermute ring) == ZeRO-DP all-gather ==
     local sequential execution."""
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.zero import zero_cdp_apply, zero_dp_apply, roll_stage_params
 n = 8
-mesh = compat_make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 key = jax.random.PRNGKey(0)
 d = 16
 stages = {"w": 0.3 * jax.random.normal(key, (n, d, d)),
@@ -142,7 +142,7 @@ rolled = roll_stage_params(stages, n)
 def run_cdp(rolled_shard, xs):
     my_params = jax.tree.map(lambda t: t[0], rolled_shard)  # drop shard dim
     return zero_cdp_apply(stage_fn, my_params, xs[0], "data", n)[None]
-f = compat_shard_map(run_cdp, mesh=mesh,
+f = jax.shard_map(run_cdp, mesh=mesh,
                   in_specs=(jax.tree.map(lambda _: P("data"), stages), P("data")),
                   out_specs=P("data"), axis_names={"data"}, check_vma=False)
 out_cdp = jax.jit(f)(rolled, x)
@@ -150,7 +150,7 @@ np.testing.assert_allclose(np.asarray(out_cdp), np.asarray(ref), rtol=2e-5, atol
 
 def run_dp(rolled_shard, xs):
     return zero_dp_apply(stage_fn, jax.tree.map(lambda t: t[0], rolled_shard), xs[0], "data", n)[None]
-fd = compat_shard_map(run_dp, mesh=mesh,
+fd = jax.shard_map(run_dp, mesh=mesh,
                   in_specs=(jax.tree.map(lambda _: P("data"), stages), P("data")),
                   out_specs=P("data"), axis_names={"data"}, check_vma=False)
 out_dp = jax.jit(fd)(rolled, x)
@@ -172,14 +172,14 @@ def test_collectives_in_hlo_match_paper_claims(subproc):
     single all-reduce burst — the paper's Table 1 communication claim, read
     off the compiled HLO."""
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, re
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
 from repro.models import init_params
 from repro.optim import sgd_momentum
 from repro.launch.roofline import parse_collectives
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_reduced("stablelm-1.6b")
 params = init_params(cfg, jax.random.PRNGKey(0))
 opt = sgd_momentum(0.9)
@@ -195,8 +195,8 @@ print({k: (v.op_counts, v.max_grad_merge_bytes()) for k, v in stats.items()})
 assert stats["cdp_v2"].op_counts["collective-permute"] > 0
 # the ring breaks the big gradient burst into chunks: the largest
 # gradient-merge collective (all-reduce / permute / reduce-scatter) shrinks.
-# (Compared per-type, not on the global max: a compat-mode param all-gather
-# outside the step can dominate both programs identically.)
+# (Compared per-type, not on the global max: a param all-gather outside the
+# step can dominate both programs identically.)
 assert stats["cdp_v2"].max_grad_merge_bytes() < stats["dp"].max_grad_merge_bytes()
 print("HLO CLAIMS OK")
 """, timeout=1200)
@@ -206,9 +206,9 @@ def test_zero1_ring_matches_baseline(subproc):
     """ZeRO-1-on-the-ring (reduce-scatter + data-sharded optimizer state +
     param all-gather) must be numerically identical to the full ring."""
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
-mesh = compat_make_mesh((4,2), ("data","model"))
+mesh = make_mesh((4,2), ("data","model"))
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
 from repro.optim import sgd_momentum
@@ -239,9 +239,9 @@ def test_cdp_random_rule_trains(subproc):
     """Beyond-paper randomized u_{i,j} (the paper's stated future work)
     trains on par with cdp_v2 and keeps delay <= 1."""
     subproc("""
-from repro.compat import make_mesh as compat_make_mesh, shard_map as compat_shard_map
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
-mesh = compat_make_mesh((4,2), ("data","model"))
+mesh = make_mesh((4,2), ("data","model"))
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
 from repro.data import make_lm_data, lm_batch_iterator

@@ -13,7 +13,7 @@ def test_reduced_dryrun_train_and_decode(subproc, arch):
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh as compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_reduced
 from repro.configs.base import InputShape
 from repro.core.trainer import TrainerConfig, init_state, make_train_step
@@ -22,7 +22,7 @@ from repro.optim import sgd_momentum
 from repro.sharding import specs as sh
 from repro.launch.roofline import parse_collectives
 
-mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_reduced({arch!r})
 
 # --- train step (CDP-v2, multi-pod axes) ---

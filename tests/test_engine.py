@@ -56,7 +56,7 @@ def test_runspec_rejects_bad_backend():
 def test_trainer_validates_registry_not_alias_string():
     """make_train_step fails fast on a bad backend through the registry."""
     from repro.core.trainer import TrainerConfig, make_train_step
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.optim import sgd_momentum
     cfg = get_reduced("stablelm-1.6b").with_(attn_backend="bogus")
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -187,7 +187,7 @@ def test_optimizer_slot_keys_derived_from_structure():
 
 
 def test_state_shardings_shard_custom_slots():
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.sharding import specs as sh
     cfg = get_reduced("stablelm-1.6b")
     params = init_params(cfg, jax.random.PRNGKey(0))

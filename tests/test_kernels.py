@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hyp_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 from repro.models.attention import blockwise_attention
@@ -110,7 +111,7 @@ def test_pallas_backend_train_step_all_rules():
     """One CPU training step through the fused-kernel attention path
     (attn_backend='pallas', interpret) under every update rule."""
     import jax.numpy as jnp
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_reduced
     from repro.core.trainer import TrainerConfig, init_state, jit_train_step
     from repro.models import init_params

@@ -63,7 +63,7 @@ def test_engine_rejects_bad_plan_before_jax_work():
 
 
 def test_zero_cdp_mesh_validation():
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="needs a 'data' axis"):
         get_plan("zero_cdp").validate_mesh(mesh)
@@ -131,7 +131,7 @@ def test_zero_cdp_matches_dp_trajectory(subproc):
     trajectory matches DP within the 1-step-delay tolerance."""
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
 from repro.data import make_lm_data, lm_batch_iterator
@@ -197,7 +197,7 @@ def test_zero_cdp_hlo_streams_without_all_gather(subproc):
     all-reduces, orders of magnitude below the parameter bytes)."""
     subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_reduced
 from repro.core.trainer import TrainerConfig, init_state, jit_train_step
 from repro.launch.roofline import parse_collectives

@@ -1,7 +1,8 @@
 """Properties of the cyclic schedule — the paper's Fig. 1 / Table 1 claims."""
 import numpy as np
 import pytest
-from hyp_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import schedule as S
 
