@@ -217,9 +217,10 @@ def make_train_step(cfg, trainer: TrainerConfig, mesh, opt: Optimizer,
         if rule == RULE_DP or params_prev is None:
             theta_hat = params
         else:
-            ids = model_mod.param_stage_ids(cfg, params, n_data)
-            thr = fresh_threshold_traced(rule, i, n_data, step)
-            theta_hat = select_params(params, params_prev, ids, thr)
+            with jax.named_scope("update"):
+                ids = model_mod.param_stage_ids(cfg, params, n_data)
+                thr = fresh_threshold_traced(rule, i, n_data, step)
+                theta_hat = select_params(params, params_prev, ids, thr)
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             theta_hat, batch)
         grads = grad_sync.sync_gradients(
@@ -276,7 +277,9 @@ def make_train_step(cfg, trainer: TrainerConfig, mesh, opt: Optimizer,
             scale = jnp.minimum(1.0, trainer.grad_clip / (gnorm + 1e-9))
             grads = jax.tree.map(lambda g: (g * scale).astype(g.dtype), grads)
         lr = lr_fn(state["step"])
-        new_params, new_opt = opt.update(grads, state["opt"], params, lr)
+        with jax.named_scope("update"):
+            new_params, new_opt = opt.update(grads, state["opt"], params,
+                                             lr)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         if use_prev:

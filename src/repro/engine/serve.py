@@ -73,6 +73,7 @@ dataclass, which additionally configures
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
@@ -95,6 +96,7 @@ def _sampler():
     import jax
     import jax.numpy as jnp
 
+    @jax.named_scope("sample")
     def sample(logits, keys, temps, topks):
         def one(k, lg, temp, tk):
             nk, sub = jax.random.split(k)
@@ -782,6 +784,8 @@ class ServeEngine:
         import jax.numpy as jnp
         import numpy as np
 
+        from jax.profiler import TraceAnnotation as span
+
         self.build()
         policy, eos_id = sp.policy, sp.eos_id
         deadline_steps, queue_limit = sp.deadline_steps, sp.queue_limit
@@ -1167,498 +1171,540 @@ class ServeEngine:
             if t >= max_steps:
                 truncated = True         # graceful: time the stragglers
                 break                    # out below instead of raising
-            now = time.perf_counter()
-            cnow = clock_now()
-            if paged:
-                pool.step = t            # stamp allocator events
-            # -- arrivals (bounded admission queue) --------------------------
-            n_arrived = 0
-            for r in pending:
-                if arr_of(r) > cnow:
-                    break                # pending is sorted by arrival
-                n_arrived += 1
-                arrival_wall.setdefault(r.rid, now)
-                if queue_limit is not None and len(waiting) >= queue_limit:
-                    self._reject(r, f"admission queue full "
-                                    f"(queue_limit={queue_limit})")
-                    rejects_ct += 1
-                else:
-                    waiting.append(r)
-            pending = pending[n_arrived:]
-            # -- deadline expiry (waiting, then live) ------------------------
-            still = []
-            for r in waiting:
-                d = ddl_of(r)
-                if d is not None and cnow - arr_of(r) >= d:
-                    r.status = "timeout"
-                    r.error = f"deadline of {d:g} {unit} expired in queue"
-                    r.tokens = np.zeros((0,), np.int32)
-                    self.events.append("timeout", t, rid=r.rid,
-                                       where="queue")
-                    timeouts_ct += 1
-                else:
-                    still.append(r)
-            waiting = still
-            for slot in sched.live_slots():
-                r = sched.requests[sched.owner[slot]]
-                d = ddl_of(r)
-                if d is not None and cnow - arr_of(r) >= d:
-                    rid = sched.evict(slot, t, now, "timeout")
-                    sched.requests[rid].status = "timeout"
-                    sched.requests[rid].error = (f"deadline of {d:g} {unit} "
-                                                 f"expired mid-decode")
-                    release_slot_resources(slot)
-                    self.events.append("timeout", t, rid=rid, where="slot")
-                    timeouts_ct += 1
-            for rid in list(parked):
-                r = sched.requests[rid]
-                d = ddl_of(r)
-                if d is not None and cnow - arr_of(r) >= d:
-                    parked.pop(rid)      # payload dropped with it
-                    r.status = "timeout"
-                    r.error = (f"deadline of {d:g} {unit} expired while "
-                               f"parked")
-                    sched.close(rid, t, now, "timeout")
-                    self.events.append("timeout", t, rid=rid,
-                                       where="parked")
-                    timeouts_ct += 1
-            # -- admissions --------------------------------------------------
-            elig_ok = not (policy == "static" and sched.live_slots())
-            if paged:
-                was_live = bool(sched.live_slots())
-                # parked level-1 wakes first: bitwise restore, no prefill
-                if elig_ok:
+            with span("serve.iter"):
+                now = time.perf_counter()
+                cnow = clock_now()
+                if paged:
+                    pool.step = t            # stamp allocator events
+                # -- arrivals (bounded admission queue) -----------------------
+                with span("serve.arrivals"):
+                    n_arrived = 0
+                    for r in pending:
+                        if arr_of(r) > cnow:
+                            break                # pending is sorted by arrival
+                        n_arrived += 1
+                        arrival_wall.setdefault(r.rid, now)
+                        if queue_limit is not None and \
+                                len(waiting) >= queue_limit:
+                            self._reject(r, f"admission queue full "
+                                            f"(queue_limit={queue_limit})")
+                            rejects_ct += 1
+                        else:
+                            waiting.append(r)
+                    pending = pending[n_arrived:]
+                    # -- deadline expiry (waiting, then live) -----------------
+                    still = []
+                    for r in waiting:
+                        d = ddl_of(r)
+                        if d is not None and cnow - arr_of(r) >= d:
+                            r.status = "timeout"
+                            r.error = (f"deadline of {d:g} {unit} expired "
+                                       f"in queue")
+                            r.tokens = np.zeros((0,), np.int32)
+                            self.events.append("timeout", t, rid=r.rid,
+                                               where="queue")
+                            timeouts_ct += 1
+                        else:
+                            still.append(r)
+                    waiting = still
+                    for slot in sched.live_slots():
+                        r = sched.requests[sched.owner[slot]]
+                        d = ddl_of(r)
+                        if d is not None and cnow - arr_of(r) >= d:
+                            rid = sched.evict(slot, t, now, "timeout")
+                            sched.requests[rid].status = "timeout"
+                            sched.requests[rid].error = (
+                                f"deadline of {d:g} {unit} expired "
+                                f"mid-decode")
+                            release_slot_resources(slot)
+                            self.events.append("timeout", t, rid=rid,
+                                               where="slot")
+                            timeouts_ct += 1
                     for rid in list(parked):
-                        if parked[rid].level == 1 and \
-                                try_wake_level1(parked[rid]):
-                            parked.pop(rid)
-                # then level-2 resumes (re-prefill at prompt_len + gen
-                # width) and fresh admissions, one block-pool plan each
-                cands = []
-                if elig_ok:
-                    cands = [(sched.requests[rid], parked[rid])
-                             for rid in list(parked)
-                             if parked[rid].level == 2]
-                    cands += [(r, None) for r in
-                              admission_order(len(sched.free_slots()))]
-                S_res = S_pad + self.gen
-                plans = {}                  # (kind, width) -> [admission]
-                cow_pairs, cow_pins, poison_slots = [], [], []
-                admitted_rids = set()
-                for req, p in cands:
-                    free_now = sched.free_slots()
-                    if not free_now:
-                        break
-                    slot = free_now[0]
-                    prompt = np.asarray(req.prompt, np.int64)
-                    if p is not None and p.generated:
-                        prompt = np.concatenate(
-                            [prompt, np.asarray(p.generated, np.int64)])
-                    try:
-                        hist_n, cow = pool.admit(
-                            slot, prompt, pending_all=bool(prefill_chunk))
-                    except paging.PoolExhausted:
-                        break       # completions will free blocks; wait
-                    sched.admit(slot, req, t, len(history),
-                                resume=p is not None,
-                                prefilling=bool(prefill_chunk))
-                    set_sampling(slot, req)
-                    refresh_row(slot)
-                    poisoned = (self.injector is not None and
-                                self.injector.fires("poison_request",
-                                                    req.rid))
-                    if prefill_chunk:
-                        # chunked admission bypasses the plans machinery:
-                        # the chunk phase below prefills positions
-                        # hist_n.. one chunk per iteration (a prefix-cache
-                        # hit skips straight to the first cold chunk). The
-                        # full-hit CoW copy runs NOW — its source blocks
-                        # already hold written content.
-                        req.status = "prefilling"
-                        row_len[slot] = hist_n
-                        if cow:
-                            do_cow([cow[:2]])
-                        prefill_jobs[slot] = {
-                            "req": req, "prompt": prompt, "off": hist_n,
-                            "hist0": hist_n,
-                            "blocks": [b for b in pool.slot_blocks[slot]
-                                       if b in pool.pending],
-                            "poison": poisoned}
+                        r = sched.requests[rid]
+                        d = ddl_of(r)
+                        if d is not None and cnow - arr_of(r) >= d:
+                            parked.pop(rid)      # payload dropped with it
+                            r.status = "timeout"
+                            r.error = (f"deadline of {d:g} {unit} expired "
+                                       f"while parked")
+                            sched.close(rid, t, now, "timeout")
+                            self.events.append("timeout", t, rid=rid,
+                                               where="parked")
+                            timeouts_ct += 1
+                # -- admissions -----------------------------------------------
+                with (span("serve.admit") if waiting or parked
+                      else contextlib.nullcontext()):
+                    elig_ok = not (policy == "static" and sched.live_slots())
+                    if paged:
+                        was_live = bool(sched.live_slots())
+                        # parked level-1 wakes first: bitwise restore, no
+                        # prefill
+                        if elig_ok:
+                            for rid in list(parked):
+                                if parked[rid].level == 1 and \
+                                        try_wake_level1(parked[rid]):
+                                    parked.pop(rid)
+                        # then level-2 resumes (re-prefill at prompt_len + gen
+                        # width) and fresh admissions, one block-pool plan each
+                        cands = []
+                        if elig_ok:
+                            cands = [(sched.requests[rid], parked[rid])
+                                     for rid in list(parked)
+                                     if parked[rid].level == 2]
+                            cands += [(r, None) for r in
+                                      admission_order(len(sched.free_slots()))]
+                        S_res = S_pad + self.gen
+                        plans = {}          # (kind, width) -> [admission]
+                        cow_pairs, cow_pins, poison_slots = [], [], []
+                        admitted_rids = set()
+                        for req, p in cands:
+                            free_now = sched.free_slots()
+                            if not free_now:
+                                break
+                            slot = free_now[0]
+                            prompt = np.asarray(req.prompt, np.int64)
+                            if p is not None and p.generated:
+                                prompt = np.concatenate(
+                                    [prompt,
+                                     np.asarray(p.generated, np.int64)])
+                            try:
+                                hist_n, cow = pool.admit(
+                                    slot, prompt,
+                                    pending_all=bool(prefill_chunk))
+                            except paging.PoolExhausted:
+                                break   # completions will free blocks; wait
+                            sched.admit(slot, req, t, len(history),
+                                        resume=p is not None,
+                                        prefilling=bool(prefill_chunk))
+                            set_sampling(slot, req)
+                            refresh_row(slot)
+                            poisoned = (self.injector is not None and
+                                        self.injector.fires("poison_request",
+                                                            req.rid))
+                            if prefill_chunk:
+                                # chunked admission bypasses the plans
+                                # machinery: the chunk phase below prefills
+                                # positions hist_n.. one chunk per iteration
+                                # (a prefix-cache hit skips straight to the
+                                # first cold chunk). The full-hit CoW copy
+                                # runs NOW — its source blocks already hold
+                                # written content.
+                                req.status = "prefilling"
+                                row_len[slot] = hist_n
+                                if cow:
+                                    do_cow([cow[:2]])
+                                prefill_jobs[slot] = {
+                                    "req": req, "prompt": prompt,
+                                    "off": hist_n, "hist0": hist_n,
+                                    "blocks": [b for b in
+                                               pool.slot_blocks[slot]
+                                               if b in pool.pending],
+                                    "poison": poisoned}
+                            else:
+                                row_len[slot] = len(prompt)
+                                if cow:
+                                    # the device copy is deferred until the
+                                    # source's content is valid — pin it so
+                                    # a later admission in this round cannot
+                                    # reclaim + overwrite it first
+                                    cow_pairs.append(cow[:2])
+                                    cow_pins.append(cow[0])
+                                    pool.pin(cow[0])
+                                key2 = ("shared" if hist_n else "fresh",
+                                        S_pad if p is None else S_res)
+                                plans.setdefault(key2, []).append(
+                                    (slot, req, prompt, hist_n))
+                            if was_live and t > 0:
+                                admitted_mid_decode += 1
+                            if p is not None:
+                                parked.pop(req.rid)
+                                wakes += 1
+                                pool._log("page_wake", slot, req.rid)
+                                self.events.append("wake", t, rid=req.rid,
+                                                   slot=slot, level=2)
+                            else:
+                                admitted_rids.add(req.rid)
+                            if poisoned:
+                                if not prefill_chunk:
+                                    poison_slots.append(slot)
+                                self.events.append("inject", t,
+                                                   site="poison_request",
+                                                   rid=req.rid, slot=slot)
+                        waiting = [r for r in waiting
+                                   if r.rid not in admitted_rids]
+                        if plans:
+                            cache["table"] = jnp.asarray(st["table"].copy())
+                            # fresh admissions prefill (and REGISTER their
+                            # blocks) before shared ones read them —
+                            # intra-batch sharing. CoW copies run BETWEEN the
+                            # two: after the fresh prefills have written every
+                            # source block, before any shared prefill reads
+                            # its private copy.
+                            order = sorted(plans,
+                                           key=lambda k: k[0] != "fresh")
+                            cow_done = False
+                            for kind, width in order:
+                                if kind == "shared" and not cow_done:
+                                    do_cow(cow_pairs)
+                                    for b in cow_pins:
+                                        pool.unpin(b)
+                                    cow_done = True
+                                items = plans[(kind, width)]
+                                prompts = np.zeros((B, width), np.int32)
+                                lengths = np.zeros((B,), np.int32)
+                                hist_a = np.zeros((B,), np.int32)
+                                mask = np.zeros((B,), bool)
+                                rids = np.zeros((B,), np.int32)
+                                if kind == "shared":
+                                    # non-admitted rows: empty tail at
+                                    # their own length — no writes, lengths
+                                    # preserved
+                                    lengths[:] = row_len
+                                    hist_a[:] = row_len
+                                for slot, req, prompt, hist_n in items:
+                                    mask[slot] = True
+                                    rids[slot] = _sid(req)
+                                    lengths[slot] = len(prompt)
+                                    hist_a[slot] = hist_n
+                                    tail = (prompt[hist_n:]
+                                            if kind == "shared" else prompt)
+                                    prompts[slot, :len(tail)] = tail
+                                if kind == "fresh":
+                                    tok, cache, keys = fns["admit_fresh"](
+                                        self.params, jnp.asarray(prompts),
+                                        jnp.asarray(np.maximum(lengths, 1)),
+                                        jnp.asarray(mask), jnp.asarray(rids),
+                                        tok, cache, keys, *samp())
+                                else:
+                                    tok, cache, keys = fns["admit_shared"](
+                                        self.params, jnp.asarray(prompts),
+                                        jnp.asarray(lengths),
+                                        jnp.asarray(hist_a),
+                                        jnp.asarray(mask), jnp.asarray(rids),
+                                        tok, cache, keys, *samp())
+                                prefill_calls += 1
+                            if not cow_done:    # defensive: cow, no shared
+                                do_cow(cow_pairs)
+                                for b in cow_pins:
+                                    pool.unpin(b)
+                            # every planned prefill has executed: blocks
+                            # registered by this round's shared-tail
+                            # admissions now hold real content and become
+                            # prefix-matchable again
+                            pool.mark_written()
+                            if poison_slots:
+                                paged_poison(poison_slots)
                     else:
-                        row_len[slot] = len(prompt)
-                        if cow:
-                            # the device copy is deferred until the
-                            # source's content is valid — pin it so a
-                            # later admission in this round cannot reclaim
-                            # + overwrite it first
-                            cow_pairs.append(cow[:2])
-                            cow_pins.append(cow[0])
-                            pool.pin(cow[0])
-                        key2 = ("shared" if hist_n else "fresh",
-                                S_pad if p is None else S_res)
-                        plans.setdefault(key2, []).append(
-                            (slot, req, prompt, hist_n))
-                    if was_live and t > 0:
-                        admitted_mid_decode += 1
-                    if p is not None:
-                        parked.pop(req.rid)
-                        wakes += 1
-                        pool._log("page_wake", slot, req.rid)
-                        self.events.append("wake", t, rid=req.rid,
-                                           slot=slot, level=2)
-                    else:
-                        admitted_rids.add(req.rid)
-                    if poisoned:
-                        if not prefill_chunk:
-                            poison_slots.append(slot)
-                        self.events.append("inject", t,
-                                           site="poison_request",
-                                           rid=req.rid, slot=slot)
-                waiting = [r for r in waiting if r.rid not in admitted_rids]
-                if plans:
-                    cache["table"] = jnp.asarray(st["table"].copy())
-                    # fresh admissions prefill (and REGISTER their blocks)
-                    # before shared ones read them — intra-batch sharing.
-                    # CoW copies run BETWEEN the two: after the fresh
-                    # prefills have written every source block, before any
-                    # shared prefill reads its private copy.
-                    order = sorted(plans, key=lambda k: k[0] != "fresh")
-                    cow_done = False
-                    for kind, width in order:
-                        if kind == "shared" and not cow_done:
-                            do_cow(cow_pairs)
-                            for b in cow_pins:
-                                pool.unpin(b)
-                            cow_done = True
-                        items = plans[(kind, width)]
-                        prompts = np.zeros((B, width), np.int32)
+                        free = sched.free_slots()
+                        elig = admission_order(len(free)) if elig_ok else []
+                        take = min(len(free), len(elig))
+                        if take and prefill_chunk:
+                            # chunked admission: allocate the slot and open a
+                            # prefill job — the chunk phase below pushes the
+                            # first chunk THIS iteration, so scheduling is
+                            # unchanged
+                            was_live = bool(sched.live_slots())
+                            admitted_rids = set()
+                            for slot, req in zip(free[:take], elig[:take]):
+                                sched.admit(slot, req, t, len(history),
+                                            prefilling=True)
+                                req.status = "prefilling"
+                                set_sampling(slot, req)
+                                poisoned = (
+                                    self.injector is not None and
+                                    self.injector.fires("poison_request",
+                                                        req.rid))
+                                if poisoned:
+                                    self.events.append("inject", t,
+                                                       site="poison_request",
+                                                       rid=req.rid, slot=slot)
+                                prefill_jobs[slot] = {
+                                    "req": req,
+                                    "prompt": np.asarray(req.prompt, np.int64),
+                                    "off": 0, "hist0": 0, "blocks": [],
+                                    "poison": poisoned}
+                                admitted_rids.add(req.rid)
+                                if was_live and t > 0:
+                                    admitted_mid_decode += 1
+                            waiting = [r for r in waiting
+                                       if r.rid not in admitted_rids]
+                        elif take:
+                            was_live = bool(sched.live_slots())
+                            prompts = np.zeros((B, S_pad), np.int32)
+                            lengths = np.ones((B,), np.int32)
+                            mask = np.zeros((B,), bool)
+                            rids = np.zeros((B,), np.int32)
+                            poison = np.zeros((B,), bool)
+                            admitted_rids = set()
+                            for slot, req in zip(free[:take], elig[:take]):
+                                prompts[slot, :len(req.prompt)] = req.prompt
+                                lengths[slot] = len(req.prompt)
+                                mask[slot] = True
+                                rids[slot] = _sid(req)
+                                sched.admit(slot, req, t, len(history))
+                                set_sampling(slot, req)
+                                admitted_rids.add(req.rid)
+                                if was_live and t > 0:
+                                    admitted_mid_decode += 1
+                                if self.injector is not None and \
+                                        self.injector.fires("poison_request",
+                                                            req.rid):
+                                    poison[slot] = True
+                                    self.events.append("inject", t,
+                                                       site="poison_request",
+                                                       rid=req.rid, slot=slot)
+                            waiting = [r for r in waiting
+                                       if r.rid not in admitted_rids]
+                            tok, cache, keys = fns["admit"](
+                                self.params, jnp.asarray(prompts),
+                                jnp.asarray(lengths), jnp.asarray(mask),
+                                jnp.asarray(rids), tok, cache, keys, *samp())
+                            prefill_calls += 1
+                            if poison.any():
+                                cache = fns["poison"](cache,
+                                                      jnp.asarray(poison))
+                # -- chunked prefill: every prefilling slot advances ONE chunk
+                # (one batched call per iteration; rows on their FINAL chunk
+                # sample their first token exactly like a legacy admission, so
+                # it is logged as this iteration's emission)
+                if prefill_jobs:
+                    with span("serve.prefill"):
+                        C = prefill_chunk
+                        tails = np.zeros((B, C), np.int32)
                         lengths = np.zeros((B,), np.int32)
                         hist_a = np.zeros((B,), np.int32)
                         mask = np.zeros((B,), bool)
                         rids = np.zeros((B,), np.int32)
-                        if kind == "shared":
-                            # non-admitted rows: empty tail at their own
-                            # length — no writes, lengths preserved
-                            lengths[:] = row_len
-                            hist_a[:] = row_len
-                        for slot, req, prompt, hist_n in items:
-                            mask[slot] = True
-                            rids[slot] = _sid(req)
-                            lengths[slot] = len(prompt)
-                            hist_a[slot] = hist_n
-                            tail = prompt[hist_n:] if kind == "shared" \
-                                else prompt
-                            prompts[slot, :len(tail)] = tail
-                        if kind == "fresh":
-                            tok, cache, keys = fns["admit_fresh"](
-                                self.params, jnp.asarray(prompts),
-                                jnp.asarray(np.maximum(lengths, 1)),
-                                jnp.asarray(mask), jnp.asarray(rids), tok,
-                                cache, keys, *samp())
-                        else:
-                            tok, cache, keys = fns["admit_shared"](
-                                self.params, jnp.asarray(prompts),
-                                jnp.asarray(lengths), jnp.asarray(hist_a),
-                                jnp.asarray(mask), jnp.asarray(rids), tok,
-                                cache, keys, *samp())
+                        # passenger rows: empty tail at their own EXACT
+                        # length — no writes, length preserved (the paged
+                        # shared-tail admission convention)
+                        mirror = row_len if paged else dense_len
+                        lengths[:] = mirror
+                        hist_a[:] = mirror
+                        finals = []
+                        for slot, job in prefill_jobs.items():
+                            prompt, off = job["prompt"], job["off"]
+                            end = min(off + C, len(prompt))
+                            tails[slot, :end - off] = prompt[off:end]
+                            lengths[slot] = end
+                            hist_a[slot] = off
+                            last = end >= len(prompt)
+                            mask[slot] = last
+                            rids[slot] = _sid(job["req"])
+                            job["off"] = end
+                            if last:
+                                finals.append((slot, job))
+                        chunk_fn = fns["admit_shared"] if paged \
+                            else fns["admit_chunk"]
+                        if paged:
+                            cache["table"] = jnp.asarray(st["table"].copy())
+                        tok, cache, keys = chunk_fn(
+                            self.params, jnp.asarray(tails),
+                            jnp.asarray(lengths),
+                            jnp.asarray(hist_a), jnp.asarray(mask),
+                            jnp.asarray(rids), tok, cache, keys, *samp())
                         prefill_calls += 1
-                    if not cow_done:        # defensive: cow without shared
+                        for slot, job in prefill_jobs.items():
+                            mirror[slot] = job["off"]
+                        pmask = np.zeros((B,), bool)
+                        for slot, job in finals:
+                            prefill_jobs.pop(slot)
+                            req = job["req"]
+                            sched.prefill_done(slot, t, len(history))
+                            req.status = "queued"
+                            if paged:
+                                pool.mark_written(job["blocks"])
+                            pmask[slot] = job["poison"]
+                            self.events.append(
+                                "prefill_done", t, rid=req.rid, slot=slot,
+                                hist=job["hist0"],
+                                chunks=-(-(len(job["prompt"]) -
+                                           job["hist0"]) // C))
+                        if pmask.any():
+                            if paged:
+                                paged_poison([s for s, j in finals
+                                              if j["poison"]])
+                            else:
+                                cache = fns["poison"](cache,
+                                                      jnp.asarray(pmask))
+                # -- paged: make every live row's next write position resident
+                # (BEFORE the emission is logged: a preempted row's pending
+                # token stays pending, so its wake re-injects it exactly once)
+                if paged and sched.live_slots():
+                    with span("serve.kv_grow"):
+                        cow_pairs, dirty = [], False
+                        for slot in list(sched.live_slots()):
+                            rid = sched.owner[slot]
+                            if rid is None or slot in sched.prefilling:
+                                continue  # parked victim, or mid-prefill
+                            # the block is allocated even for a request
+                            # completing this step (released again at
+                            # completion): the decode READS the position it
+                            # just wrote, so the write must land in a real
+                            # exclusive block — writes redirected to the
+                            # write-off path are dropped, not read back
+                            while sched.owner[slot] is not None:
+                                try:
+                                    new, cow = pool.prepare_write(
+                                        slot, int(row_len[slot]))
+                                except paging.PoolExhausted:
+                                    # LIFO victim — maybe self
+                                    park(lifo_victim(),
+                                         f"pool exhausted growing slot {slot}")
+                                    continue
+                                for lb, phys in new:
+                                    st["table"][slot, lb] = phys
+                                    dirty = True
+                                if cow:
+                                    cow_pairs.append(cow[:2])
+                                    st["table"][slot, cow[2]] = cow[1]
+                                    dirty = True
+                                break
                         do_cow(cow_pairs)
-                        for b in cow_pins:
-                            pool.unpin(b)
-                    # every planned prefill has executed: blocks registered
-                    # by this round's shared-tail admissions now hold real
-                    # content and become prefix-matchable again
-                    pool.mark_written()
-                    if poison_slots:
-                        paged_poison(poison_slots)
-            else:
-                free = sched.free_slots()
-                elig = admission_order(len(free)) if elig_ok else []
-                take = min(len(free), len(elig))
-                if take and prefill_chunk:
-                    # chunked admission: allocate the slot and open a
-                    # prefill job — the chunk phase below pushes the first
-                    # chunk THIS iteration, so scheduling is unchanged
-                    was_live = bool(sched.live_slots())
-                    admitted_rids = set()
-                    for slot, req in zip(free[:take], elig[:take]):
-                        sched.admit(slot, req, t, len(history),
-                                    prefilling=True)
-                        req.status = "prefilling"
-                        set_sampling(slot, req)
-                        poisoned = (self.injector is not None and
-                                    self.injector.fires("poison_request",
-                                                        req.rid))
-                        if poisoned:
-                            self.events.append("inject", t,
-                                               site="poison_request",
-                                               rid=req.rid, slot=slot)
-                        prefill_jobs[slot] = {
-                            "req": req,
-                            "prompt": np.asarray(req.prompt, np.int64),
-                            "off": 0, "hist0": 0, "blocks": [],
-                            "poison": poisoned}
-                        admitted_rids.add(req.rid)
-                        if was_live and t > 0:
-                            admitted_mid_decode += 1
-                    waiting = [r for r in waiting
-                               if r.rid not in admitted_rids]
-                elif take:
-                    was_live = bool(sched.live_slots())
-                    prompts = np.zeros((B, S_pad), np.int32)
-                    lengths = np.ones((B,), np.int32)
-                    mask = np.zeros((B,), bool)
-                    rids = np.zeros((B,), np.int32)
-                    poison = np.zeros((B,), bool)
-                    admitted_rids = set()
-                    for slot, req in zip(free[:take], elig[:take]):
-                        prompts[slot, :len(req.prompt)] = req.prompt
-                        lengths[slot] = len(req.prompt)
-                        mask[slot] = True
-                        rids[slot] = _sid(req)
-                        sched.admit(slot, req, t, len(history))
-                        set_sampling(slot, req)
-                        admitted_rids.add(req.rid)
-                        if was_live and t > 0:
-                            admitted_mid_decode += 1
-                        if self.injector is not None and \
-                                self.injector.fires("poison_request",
-                                                    req.rid):
-                            poison[slot] = True
-                            self.events.append("inject", t,
-                                               site="poison_request",
-                                               rid=req.rid, slot=slot)
-                    waiting = [r for r in waiting
-                               if r.rid not in admitted_rids]
-                    tok, cache, keys = fns["admit"](
-                        self.params, jnp.asarray(prompts),
-                        jnp.asarray(lengths), jnp.asarray(mask),
-                        jnp.asarray(rids), tok, cache, keys, *samp())
-                    prefill_calls += 1
-                    if poison.any():
-                        cache = fns["poison"](cache, jnp.asarray(poison))
-            # -- chunked prefill: every prefilling slot advances ONE chunk --
-            # (one batched call per iteration; rows on their FINAL chunk
-            # sample their first token exactly like a legacy admission, so
-            # it is logged as this iteration's emission)
-            if prefill_jobs:
-                C = prefill_chunk
-                tails = np.zeros((B, C), np.int32)
-                lengths = np.zeros((B,), np.int32)
-                hist_a = np.zeros((B,), np.int32)
-                mask = np.zeros((B,), bool)
-                rids = np.zeros((B,), np.int32)
-                # passenger rows: empty tail at their own EXACT length —
-                # no writes, length preserved (the paged shared-tail
-                # admission convention)
-                mirror = row_len if paged else dense_len
-                lengths[:] = mirror
-                hist_a[:] = mirror
-                finals = []
-                for slot, job in prefill_jobs.items():
-                    prompt, off = job["prompt"], job["off"]
-                    end = min(off + C, len(prompt))
-                    tails[slot, :end - off] = prompt[off:end]
-                    lengths[slot] = end
-                    hist_a[slot] = off
-                    last = end >= len(prompt)
-                    mask[slot] = last
-                    rids[slot] = _sid(job["req"])
-                    job["off"] = end
-                    if last:
-                        finals.append((slot, job))
-                chunk_fn = fns["admit_shared"] if paged \
-                    else fns["admit_chunk"]
-                if paged:
-                    cache["table"] = jnp.asarray(st["table"].copy())
-                tok, cache, keys = chunk_fn(
-                    self.params, jnp.asarray(tails), jnp.asarray(lengths),
-                    jnp.asarray(hist_a), jnp.asarray(mask),
-                    jnp.asarray(rids), tok, cache, keys, *samp())
-                prefill_calls += 1
-                for slot, job in prefill_jobs.items():
-                    mirror[slot] = job["off"]
-                pmask = np.zeros((B,), bool)
-                for slot, job in finals:
-                    prefill_jobs.pop(slot)
-                    req = job["req"]
-                    sched.prefill_done(slot, t, len(history))
-                    req.status = "queued"
-                    if paged:
-                        pool.mark_written(job["blocks"])
-                    pmask[slot] = job["poison"]
-                    self.events.append(
-                        "prefill_done", t, rid=req.rid, slot=slot,
-                        hist=job["hist0"],
-                        chunks=-(-(len(job["prompt"]) - job["hist0"]) // C))
-                if pmask.any():
-                    if paged:
-                        paged_poison([s for s, j in finals if j["poison"]])
-                    else:
-                        cache = fns["poison"](cache, jnp.asarray(pmask))
-            # -- paged: make every live row's next write position resident --
-            # (BEFORE the emission is logged: a preempted row's pending
-            # token stays pending, so its wake re-injects it exactly once)
-            if paged and sched.live_slots():
-                cow_pairs, dirty = [], False
-                for slot in list(sched.live_slots()):
-                    rid = sched.owner[slot]
-                    if rid is None or slot in sched.prefilling:
-                        continue    # parked victim, or still mid-prefill
-                    # the block is allocated even for a request completing
-                    # this step (released again at completion): the decode
-                    # READS the position it just wrote, so the write must
-                    # land in a real exclusive block — writes redirected to
-                    # the write-off path are dropped, not read back
-                    while sched.owner[slot] is not None:
-                        try:
-                            new, cow = pool.prepare_write(
-                                slot, int(row_len[slot]))
-                        except paging.PoolExhausted:
-                            park(lifo_victim(),   # LIFO victim — maybe self
-                                 f"pool exhausted growing slot {slot}")
-                            continue
-                        for lb, phys in new:
-                            st["table"][slot, lb] = phys
-                            dirty = True
-                        if cow:
-                            cow_pairs.append(cow[:2])
-                            st["table"][slot, cow[2]] = cow[1]
-                            dirty = True
-                        break
-                do_cow(cow_pairs)
-                if dirty:
-                    cache["table"] = jnp.asarray(st["table"].copy())
-            live = sched.live_slots()
-            if not live:
-                if not pending and not waiting and not parked:
-                    break                # everything terminal: done
-                if clock == "wall" and pending:
-                    # real-time idle: sleep toward the next arrival instead
-                    # of spinning the iteration counter
-                    gap = arr_of(pending[0]) - clock_now()
-                    if gap > 0:
-                        time.sleep(min(gap, 0.05))
-                t += 1                   # idle tick: clock runs to the next
-                continue                 # arrival without touching devices
-            # -- fused host sync: tokens (eos / streaming) + row health ------
-            # (ONE [B]-sized transfer per iteration — never one per concern)
-            host_tok = None
-            if need_sync:
-                if guard:
-                    synced = np.asarray(fns["sync"](tok, cache))
-                    host_tok, health = synced[0], synced[1].astype(bool)
-                else:
-                    host_tok = np.asarray(tok)
-                host_syncs += 1
-                if guard:
-                    # quarantine at the emission point: a row poisoned at
-                    # admission is evicted BEFORE its first token is logged
-                    quarantine(health, time.perf_counter())
-                    live = sched.live_slots()
-                    if not live:
-                        t += 1
-                        continue
-            emitting = [(s, sched.owner[s]) for s in live
-                        if s not in sched.prefilling]
-            if emitting:
-                # -- log this iteration's emission for every emitting slot --
-                history.append(tok)
-                emission_iters += 1
-                owners = np.full((B,), -1, np.int64)
-                for s, rid in emitting:
-                    owners[s] = rid
-                    first_emit.setdefault(rid, cnow)
-                owners_log.append(owners)
-                eos_hit = None
-                if eos_id is not None:
-                    eos_hit = [bool(host_tok[s] == eos_id)
-                               for s in range(B)]
-                done_now = sched.log_emissions(t, time.perf_counter(),
-                                               eos_hit)
-                if host_tok is not None and stream_hooks:
-                    # streaming observes the host copy only — nothing
-                    # feeds back into the jitted fns
-                    for s, rid in emitting:
-                        req = sched.requests[rid]
-                        tkn = int(host_tok[s])
-                        if req.on_token is not None:
-                            req.on_token(rid, tkn, t, cnow)
-                        if self._stream_cb is not None:
-                            self._stream_cb(rid, tkn, t, cnow)
-                for s in done_now:           # completion frees the blocks;
-                    release_slot_resources(s, upload=False)
-                if paged and done_now:       # ONE table upload per step,
-                    cache["table"] = jnp.asarray(st["table"].copy())
-            # -- one ragged decode step for the whole slot batch -------------
-            # (only when an emitting row still needs it: a freshly admitted
-            # request's first token comes from the prefill, not step; a
-            # mid-prefill row neither emits nor decodes)
-            live_now = [s for s in sched.live_slots()
-                        if s not in sched.prefilling]
-            if live_now:
-                if wd is not None:
-                    wd.arm(t)
-                if prefill_chunk:
-                    # mid-prefill rows are INACTIVE: their cache writes are
-                    # dropped and their lengths stay frozen
-                    act = np.ones((B,), bool)
-                    for s in prefill_jobs:
-                        act[s] = False
-                    tok, cache, keys = fns["step_active"](
-                        self.params, tok, cache, keys, *samp(),
-                        jnp.asarray(act))
-                    if not paged:
-                        dense_len[act] += 1
-                else:
-                    tok, cache, keys = fns["step"](self.params, tok, cache,
-                                                   keys, *samp())
-                decode_steps += 1
-                if wd is not None:
-                    # the watchdog's verdict needs the step's results on
-                    # the host — opting in trades async dispatch for a
-                    # truthful per-step latency reading
-                    jax.block_until_ready(tok)
-                    over = wd.expired()
-                    if over is not None:
-                        self.events.append("slow_step", t,
-                                           elapsed_s=round(over, 6),
-                                           timeout_s=wd.timeout_s)
-                    wd.disarm()
-                if paged:
-                    for s in live_now:
-                        row_len[s] += 1
-            t += 1
-        jax.block_until_ready(tok)
-        wall = time.perf_counter() - t_start
+                        if dirty:
+                            cache["table"] = jnp.asarray(st["table"].copy())
+                live = sched.live_slots()
+                if not live:
+                    if not pending and not waiting and not parked:
+                        break                # everything terminal: done
+                    if clock == "wall" and pending:
+                        # real-time idle: sleep toward the next arrival instead
+                        # of spinning the iteration counter
+                        gap = arr_of(pending[0]) - clock_now()
+                        if gap > 0:
+                            with span("serve.wait"):
+                                time.sleep(min(gap, 0.05))
+                    t += 1               # idle tick: clock runs to the next
+                    continue             # arrival without touching devices
+                # -- fused host sync: tokens (eos / streaming) + row health ---
+                # (ONE [B]-sized transfer per iteration — never one per
+                # concern)
+                host_tok = None
+                if need_sync:
+                    with span("serve.sync"):
+                        if guard:
+                            synced = np.asarray(fns["sync"](tok, cache))
+                            host_tok = synced[0]
+                            health = synced[1].astype(bool)
+                        else:
+                            host_tok = np.asarray(tok)
+                        host_syncs += 1
+                        if guard:
+                            # quarantine at the emission point: a row
+                            # poisoned at admission is evicted BEFORE its
+                            # first token is logged
+                            quarantine(health, time.perf_counter())
+                            live = sched.live_slots()
+                            if not live:
+                                t += 1
+                                continue
+                emitting = [(s, sched.owner[s]) for s in live
+                            if s not in sched.prefilling]
+                if emitting:
+                    with span("serve.emit"):
+                        # -- log this iteration's emission for every
+                        # emitting slot
+                        history.append(tok)
+                        emission_iters += 1
+                        owners = np.full((B,), -1, np.int64)
+                        for s, rid in emitting:
+                            owners[s] = rid
+                            first_emit.setdefault(rid, cnow)
+                        owners_log.append(owners)
+                        eos_hit = None
+                        if eos_id is not None:
+                            eos_hit = [bool(host_tok[s] == eos_id)
+                                       for s in range(B)]
+                        done_now = sched.log_emissions(t, time.perf_counter(),
+                                                       eos_hit)
+                        if host_tok is not None and stream_hooks:
+                            # streaming observes the host copy only — nothing
+                            # feeds back into the jitted fns
+                            for s, rid in emitting:
+                                req = sched.requests[rid]
+                                tkn = int(host_tok[s])
+                                if req.on_token is not None:
+                                    req.on_token(rid, tkn, t, cnow)
+                                if self._stream_cb is not None:
+                                    self._stream_cb(rid, tkn, t, cnow)
+                        for s in done_now:   # completion frees the blocks;
+                            release_slot_resources(s, upload=False)
+                        if paged and done_now:   # ONE table upload a step,
+                            cache["table"] = jnp.asarray(st["table"].copy())
+                # -- one ragged decode step for the whole slot batch ----------
+                # (only when an emitting row still needs it: a freshly admitted
+                # request's first token comes from the prefill, not step; a
+                # mid-prefill row neither emits nor decodes)
+                live_now = [s for s in sched.live_slots()
+                            if s not in sched.prefilling]
+                if live_now:
+                    with span("serve.decode"):
+                        if wd is not None:
+                            wd.arm(t)
+                        if prefill_chunk:
+                            # mid-prefill rows are INACTIVE: their cache
+                            # writes are dropped and their lengths stay frozen
+                            act = np.ones((B,), bool)
+                            for s in prefill_jobs:
+                                act[s] = False
+                            tok, cache, keys = fns["step_active"](
+                                self.params, tok, cache, keys, *samp(),
+                                jnp.asarray(act))
+                            if not paged:
+                                dense_len[act] += 1
+                        else:
+                            tok, cache, keys = fns["step"](
+                                self.params, tok, cache, keys, *samp())
+                        decode_steps += 1
+                        if wd is not None:
+                            # the watchdog's verdict needs the step's results
+                            # on the host — opting in trades async dispatch
+                            # for a truthful per-step latency reading
+                            jax.block_until_ready(tok)
+                            over = wd.expired()
+                            if over is not None:
+                                self.events.append("slow_step", t,
+                                                   elapsed_s=round(over, 6),
+                                                   timeout_s=wd.timeout_s)
+                            wd.disarm()
+                        if paged:
+                            for s in live_now:
+                                row_len[s] += 1
+                t += 1
+        with span("serve.finish"):
+            jax.block_until_ready(tok)
+            wall = time.perf_counter() - t_start
 
-        if truncated:
-            now = time.perf_counter()
-            for slot in sched.live_slots():
-                rid = sched.evict(slot, t, now, "timeout")
-                sched.requests[rid].status = "timeout"
-                sched.requests[rid].error = f"max_steps={max_steps} exhausted"
-                release_slot_resources(slot)
-                self.events.append("timeout", t, rid=rid, where="max_steps")
-            for rid in list(parked):
-                parked.pop(rid)          # payload dropped with it
-                r = sched.requests[rid]
-                r.status = "timeout"
-                r.error = f"max_steps={max_steps} exhausted"
-                sched.close(rid, t, now, "timeout")
-                self.events.append("timeout", t, rid=rid, where="max_steps")
-            for r in waiting + pending:
-                r.status = "timeout"
-                r.error = f"max_steps={max_steps} exhausted"
-                r.tokens = np.zeros((0,), np.int32)
-                self.events.append("timeout", t, rid=r.rid,
-                                   where="max_steps")
-            self._log(f"serve[{policy}]: max_steps={max_steps} exhausted — "
-                      f"returning partial results")
+            if truncated:
+                now = time.perf_counter()
+                for slot in sched.live_slots():
+                    rid = sched.evict(slot, t, now, "timeout")
+                    sched.requests[rid].status = "timeout"
+                    sched.requests[rid].error = \
+                        f"max_steps={max_steps} exhausted"
+                    release_slot_resources(slot)
+                    self.events.append("timeout", t, rid=rid,
+                                       where="max_steps")
+                for rid in list(parked):
+                    parked.pop(rid)          # payload dropped with it
+                    r = sched.requests[rid]
+                    r.status = "timeout"
+                    r.error = f"max_steps={max_steps} exhausted"
+                    sched.close(rid, t, now, "timeout")
+                    self.events.append("timeout", t, rid=rid,
+                                       where="max_steps")
+                for r in waiting + pending:
+                    r.status = "timeout"
+                    r.error = f"max_steps={max_steps} exhausted"
+                    r.tokens = np.zeros((0,), np.int32)
+                    self.events.append("timeout", t, rid=r.rid,
+                                       where="max_steps")
+                self._log(f"serve[{policy}]: max_steps={max_steps} "
+                          f"exhausted — returning partial results")
 
-        hist = (np.asarray(jnp.stack(history))
-                if history else np.zeros((0, B), np.int32))   # ONE transfer
+            hist = (np.asarray(jnp.stack(history))   # ONE transfer
+                    if history else np.zeros((0, B), np.int32))
         for rid, req in sched.requests.items():
             # a request's stream may span several (history, slot) segments
             # when the paged pool preempted and resumed it

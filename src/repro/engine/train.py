@@ -639,6 +639,8 @@ class TrainEngine:
         and logging on the way. Returns the final state. Stopping early
         (``steps < self.steps``) keeps the loader alive for continuation;
         reaching the configured total closes it."""
+        from jax.profiler import StepTraceAnnotation
+
         self.build()
         total = self.steps if steps is None else steps
         t0 = time.time()
@@ -651,89 +653,101 @@ class TrainEngine:
                 else self.step_fn
             step = self.start_step
             while step < total:
-                if self._rejoin_at is not None and step >= self._rejoin_at:
-                    self.rejoin(step)
-                    step_fn = self.step_fn
-                if self.injector is not None:
-                    f = self.injector.fires("rank_down", step)
-                    if f is not None:
-                        step = self._recover_rank_down(
-                            step, dead=int(f.arg), cause="rank_down")
+                with StepTraceAnnotation("train.step", step_num=step):
+                    if self._rejoin_at is not None and \
+                            step >= self._rejoin_at:
+                        self.rejoin(step)
                         step_fn = self.step_fn
+                    if self.injector is not None:
+                        f = self.injector.fires("rank_down", step)
+                        if f is not None:
+                            step = self._recover_rank_down(
+                                step, dead=int(f.arg), cause="rank_down")
+                            step_fn = self.step_fn
+                            continue
+                    batch = self._next_batch(step)
+                    if self.injector is not None:
+                        f = self.injector.fires("slow_step", step)
+                        if f is not None:
+                            # simulated preemption stall: the run survives
+                            # it, the event log shows where the time went
+                            dur = f.arg or 0.05
+                            self.events.append("slow_step", step,
+                                               sleep_s=dur)
+                            time.sleep(dur)
+                    # the watchdog measures dispatch -> results
+                    # materialized; the first step after a (re)jit
+                    # compiles, so it is exempt
+                    armed = (self.watchdog is not None and
+                             not self._fresh_program)
+                    if armed:
+                        self.watchdog.arm(step)
+                    new_state, metrics = step_fn(self.state, batch)
+                    metrics = dict(metrics)
+                    if self.injector is not None:
+                        f = self.injector.fires("step_hang", step)
+                        if f is not None:
+                            # a hung collective: a ring peer died
+                            # mid-permute and this step never completes on
+                            # the survivors — simulated as a stall past the
+                            # watchdog deadline
+                            dur = f.arg or (1.5 * self.watchdog.timeout_s
+                                            if self.watchdog else 0.1)
+                            self.events.append("inject", step,
+                                               site="step_hang",
+                                               sleep_s=dur)
+                            time.sleep(dur)
+                    if armed:
+                        float(metrics["loss"])    # block until step is done
+                        over = self.watchdog.expired()
+                        if over is not None:
+                            # indistinguishable from a dead peer on the ring:
+                            # presume the highest rank dead and recover (its
+                            # results never land, so drop this step's output)
+                            self.events.append(
+                                "step_hang", step, elapsed_s=over,
+                                timeout_s=self.watchdog.timeout_s)
+                            self._log(f"step {step}: exceeded the "
+                                      f"{self.watchdog.timeout_s:.1f}s "
+                                      f"deadline ({over:.1f}s) — presuming "
+                                      f"a dead peer")
+                            step = self._recover_rank_down(
+                                step, dead=self._n_data - 1,
+                                cause="step_hang")
+                            step_fn = self.step_fn
+                            continue
+                    self._fresh_program = False
+                    if self.injector is not None:
+                        new_state, metrics = self._inject_step_faults(
+                            step, new_state, metrics)
+                    if self.guard is not None and \
+                            not self._healthy(step, metrics):
+                        if self._bad_streak >= self.guard_max_bad:
+                            step = self._rollback(step)
+                        else:
+                            # skip the bad update: keep params/opt, advance
+                            # the step counter — under CDP's
+                            # uniform-staleness rules this is one more
+                            # bounded delay, not a divergence
+                            self.state = self._bump_step(self.state)
+                            step += 1
                         continue
-                batch = self._next_batch(step)
-                if self.injector is not None:
-                    f = self.injector.fires("slow_step", step)
-                    if f is not None:
-                        # simulated preemption stall: the run survives it,
-                        # the event log shows where the time went
-                        dur = f.arg or 0.05
-                        self.events.append("slow_step", step, sleep_s=dur)
-                        time.sleep(dur)
-                # the watchdog measures dispatch -> results materialized;
-                # the first step after a (re)jit compiles, so it is exempt
-                armed = self.watchdog is not None and not self._fresh_program
-                if armed:
-                    self.watchdog.arm(step)
-                new_state, metrics = step_fn(self.state, batch)
-                metrics = dict(metrics)
-                if self.injector is not None:
-                    f = self.injector.fires("step_hang", step)
-                    if f is not None:
-                        # a hung collective: a ring peer died mid-permute
-                        # and this step never completes on the survivors —
-                        # simulated as a stall past the watchdog deadline
-                        dur = f.arg or (1.5 * self.watchdog.timeout_s
-                                        if self.watchdog else 0.1)
-                        self.events.append("inject", step, site="step_hang",
-                                           sleep_s=dur)
-                        time.sleep(dur)
-                if armed:
-                    float(metrics["loss"])    # block until the step is done
-                    over = self.watchdog.expired()
-                    if over is not None:
-                        # indistinguishable from a dead peer on the ring:
-                        # presume the highest rank dead and recover (its
-                        # results never land, so drop this step's output)
-                        self.events.append(
-                            "step_hang", step, elapsed_s=over,
-                            timeout_s=self.watchdog.timeout_s)
-                        self._log(f"step {step}: exceeded the "
-                                  f"{self.watchdog.timeout_s:.1f}s deadline "
-                                  f"({over:.1f}s) — presuming a dead peer")
-                        step = self._recover_rank_down(
-                            step, dead=self._n_data - 1, cause="step_hang")
-                        step_fn = self.step_fn
-                        continue
-                self._fresh_program = False
-                if self.injector is not None:
-                    new_state, metrics = self._inject_step_faults(
-                        step, new_state, metrics)
-                if self.guard is not None and \
-                        not self._healthy(step, metrics):
-                    if self._bad_streak >= self.guard_max_bad:
-                        step = self._rollback(step)
-                    else:
-                        # skip the bad update: keep params/opt, advance the
-                        # step counter — under CDP's uniform-staleness rules
-                        # this is one more bounded delay, not a divergence
-                        self.state = self._bump_step(self.state)
-                        step += 1
-                    continue
-                self.state = new_state
-                if step % self.log_every == 0 or step == total - 1:
-                    rec = {"step": step,
-                           "loss": float(metrics["loss"]),
-                           "lr": float(metrics["lr"])}
-                    self.history.append(rec)
-                    self._log(f"step {step:5d}  loss {rec['loss']:.4f}  "
-                              f"lr {rec['lr']:.4f}  {time.time()-t0:.1f}s")
-                if self.ckpt_dir and (step + 1) % self.ckpt_every == 0:
-                    self._save_checkpoint(step + 1)
-                if self.elastic and self.snapshot_every and \
-                        (step + 1) % self.snapshot_every == 0:
-                    self._take_snapshot(step + 1)
-                step += 1
+                    self.state = new_state
+                    if step % self.log_every == 0 or step == total - 1:
+                        rec = {"step": step,
+                               "loss": float(metrics["loss"]),
+                               "lr": float(metrics["lr"])}
+                        self.history.append(rec)
+                        self._log(f"step {step:5d}  loss "
+                                  f"{rec['loss']:.4f}  lr {rec['lr']:.4f}  "
+                                  f"{time.time()-t0:.1f}s")
+                    if self.ckpt_dir and \
+                            (step + 1) % self.ckpt_every == 0:
+                        self._save_checkpoint(step + 1)
+                    if self.elastic and self.snapshot_every and \
+                            (step + 1) % self.snapshot_every == 0:
+                        self._take_snapshot(step + 1)
+                    step += 1
         finally:
             if total >= self.steps:
                 self.close()

@@ -94,6 +94,7 @@ def decode_attention_kernel(q, k, v, cache_len, *, bk: int = 512,
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, 1, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -279,6 +279,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
     )
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, dv), q.dtype),
@@ -370,6 +371,7 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal: bool = True,
     )
     return pl.pallas_call(
         kernel,
+        name="flash_attention_dq",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -476,6 +478,7 @@ def flash_attention_bwd_dkv(q, k, v, do, lse, delta, *, causal: bool = True,
     )
     return pl.pallas_call(
         kernel,
+        name="flash_attention_dkv",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BKV, Sk, d), k.dtype),

@@ -120,6 +120,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, table, cache_len, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, 1, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -222,6 +223,7 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, table, q_start, kv_len,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(

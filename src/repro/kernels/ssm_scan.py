@@ -155,6 +155,7 @@ def gla_scan_kernel(q, k, v, g, *, chunk: int = 64, s_valid: int = 0,
 
     outs = pl.pallas_call(
         kernel,
+        name="gla_scan_fwd",
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
@@ -254,6 +255,7 @@ def gla_scan_bwd_kernel(q, k, v, g, states, dy, *, chunk: int = 64,
 
     dq, dk, dv, dg = pl.pallas_call(
         kernel,
+        name="gla_scan_bwd",
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), rev),

@@ -171,6 +171,7 @@ def _embed(cfg, params, tokens):
     return params["embed"][tokens]
 
 
+@jax.named_scope("head")
 def _head(cfg, params, x):
     h = apply_norm(cfg.norm, params["final_norm"], x)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -294,6 +295,7 @@ def forward(cfg: ModelConfig, params: PyTree, batch: Dict[str, Any]):
 # Loss
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("head")
 def _xent(logits, targets, mask=None):
     # one-hot contraction instead of take_along_axis: gathers along a
     # tensor-parallel (vocab-sharded) dim force GSPMD to replicate the

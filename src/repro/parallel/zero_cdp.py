@@ -338,8 +338,9 @@ def make_train_step(cfg, trainer, plan: ParallelPlan, mesh, opt,
             scale = jnp.minimum(1.0, trainer.grad_clip / (gnorm + 1e-9))
             grads = grads * scale
         lr = lr_fn(state["step"])
-        new_chunks, new_opt = opt.update({"stages": grads}, state["opt"],
-                                         {"stages": chunks}, lr)
+        with jax.named_scope("update"):
+            new_chunks, new_opt = opt.update({"stages": grads}, state["opt"],
+                                             {"stages": chunks}, lr)
         new_state = {"params": new_chunks, "opt": new_opt,
                      "step": state["step"] + 1}
         if use_prev:
