@@ -980,6 +980,9 @@ class ServeEngine:
             self._warmup(("serve_sync", B), fns["sync"], tok, cache)
         preemptions = offloads = wakes = 0
         host_syncs = emission_iters = 0
+        # paged decode: K/V blocks under the rows' lengths, and the blocks
+        # of the whole table, summed over decode steps (host lengths only)
+        decode_blocks_live = decode_blocks_visited = 0
         first_emit: Dict[int, float] = {}   # rid -> clock time of 1st token
         # chunked-prefill jobs: slot -> {req, prompt, off, hist0, blocks,
         # poison}; one chunk per job advances per scheduler iteration
@@ -1671,6 +1674,9 @@ class ServeEngine:
                         if paged:
                             for s in live_now:
                                 row_len[s] += 1
+                            decode_blocks_live += int(
+                                (-(-row_len // bs)).sum())
+                            decode_blocks_visited += B * nb_max
                 t += 1
         with span("serve.finish"):
             jax.block_until_ready(tok)
@@ -1772,6 +1778,8 @@ class ServeEngine:
                 "offloads": offloads,
                 "wakes": wakes,
                 "cow_copies": pool.cow_copies,
+                "decode_blocks_live": decode_blocks_live,
+                "decode_blocks_visited": decode_blocks_visited,
                 "sleep_level": self.sleep_level,
                 "prefix_cache": self.prefix_cache,
             }
@@ -1783,7 +1791,9 @@ class ServeEngine:
                 f"{pg['marginal_prefill_tokens']}/"
                 f"{pg['prefill_tokens_requested']} prefill tokens computed, "
                 f"{preemptions} preemptions ({offloads} offloads, "
-                f"{wakes} wakes)")
+                f"{wakes} wakes), decode blocks live/visited "
+                f"{decode_blocks_live}/{decode_blocks_visited} "
+                f"({decode_blocks_live / max(decode_blocks_visited, 1):.1%})")
         self._log(
             f"serve[{policy}]: {len(requests)} requests over {B} slots in "
             f"{wall:.2f}s — {metrics['decode_tok_s']} tok/s, "

@@ -156,13 +156,10 @@ def paged_decode_attention(q, k_pool, v_pool, table, cache_len, *,
     the trash block); table: [B,nb] int32; cache_len: [B] -> [B,1,H,dv].
     The ``paged_attn="pallas"`` decode op: KV blocks are read through the
     scalar-prefetched block table, no gather materialises the row's cache."""
-    B, _, H, dh = q.shape
-    dv = v_pool.shape[-1]
-    qh = q[:, 0].reshape(B * H, dh)
     out = paged_decode_attention_kernel(
-        qh, k_pool, v_pool, table.reshape(-1).astype(jnp.int32),
-        cache_len.astype(jnp.int32), heads=H, interpret=interpret)
-    return out.reshape(B, 1, H, dv)
+        q[:, 0], k_pool, v_pool, table.reshape(-1).astype(jnp.int32),
+        cache_len.astype(jnp.int32), interpret=interpret)
+    return out[:, None]
 
 
 @partial(jax.jit, static_argnames=("bq", "interpret"))

@@ -221,6 +221,47 @@ def test_decode_backend_parity_model_level(case):
                                rtol=1e-3)
 
 
+# ---------------------------------------------------------------------------
+# paged decode kernel vs the jnp gather reference: row lengths on and around
+# block edges, unallocated table entries on the trash block, and NaN in every
+# pool position no row's length covers (the trash block included)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("H,KV,dh,bs", [
+    (32, 2, 128, 16),      # chatglm3-6b-like GQA: 16 query heads a group
+    (4, 4, 32, 16),        # MHA (one query head a group)
+    (32, 32, 64, 128),     # MHA tile too wide for one step: a group a step
+], ids=["gqa", "mha", "mha_wide"])
+def test_paged_decode_attention_vs_gather_ref(H, KV, dh, bs):
+    from repro.models.attention import _paged_gather, decode_attention
+    nb = 4 if bs == 16 else 2
+    lens = np.array([0, 1, bs, bs + 1, nb * bs])
+    B, NB = len(lens), len(lens) * nb
+    rng = np.random.default_rng(0)
+    k = rng.standard_normal((NB + 1, KV, bs, dh)).astype(np.float32)
+    v = rng.standard_normal((NB + 1, KV, bs, dh)).astype(np.float32)
+    table = np.full((B, nb), NB, np.int32)     # NB: the trash block
+    free = list(rng.permutation(NB))
+    for b, n in enumerate(lens):
+        for j in range(-(-n // bs)):
+            table[b, j] = free.pop()
+            k[table[b, j], :, n - j * bs:] = np.nan
+            v[table[b, j], :, n - j * bs:] = np.nan
+    k[free + [NB]] = np.nan
+    v[free + [NB]] = np.nan
+    q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
+    k, v, table = jnp.asarray(k), jnp.asarray(v), jnp.asarray(table)
+    ln = jnp.asarray(lens, jnp.int32)
+
+    out = ops.paged_decode_attention(q, k, v, table, ln, interpret=True)
+    gk, gv = _paged_gather(k, table), _paged_gather(v, table)
+    vmask = jnp.arange(nb * bs)[None, :, None, None] < ln[:, None, None, None]
+    ref = decode_attention(q, gk, jnp.where(vmask, gv, 0), ln, backend="jnp")
+    assert not np.isnan(np.asarray(out)).any()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                               rtol=2e-5)
+
+
 def test_gla_pallas_forward_dispatch_and_grad():
     """ssm_scan="pallas" forward == jnp chunked scan, and jax.grad through
     the dispatch (kernel fwd + jnp-recompute bwd) == plain jnp grad."""

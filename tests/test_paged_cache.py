@@ -18,7 +18,8 @@ still referenced.
 import numpy as np
 import pytest
 
-from repro.engine import BlockPool, PoolExhausted, Request, RunSpec
+from repro.engine import (BlockPool, PoolExhausted, Request, RunSpec,
+                          ServePolicy)
 from repro.engine.serve import ServeEngine
 
 SPEC = RunSpec(arch="stablelm-1.6b", reduced=True, mesh_data=1, mesh_model=1)
@@ -509,3 +510,28 @@ def test_sleep2_wake_on_shared_prefix_under_preemption_storm():
     assert pool.blocks_in_use() == 0 and (pool.ref == 0).all()
     assert not pool.pending
     pool.audit()
+
+
+def test_decode_block_counters_match_hand_count(paged_nopfx):
+    """``decode_blocks_live`` sums, over decode steps, the 4-token blocks
+    under each row's length as the kernel sees it (the new token
+    included; a free row holds none); ``decode_blocks_visited`` sums the
+    whole table, slots x blocks a row."""
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=0, prompt=_prompt(rng, 5), max_gen=4,
+                    arrival_step=0),
+            Request(rid=1, prompt=_prompt(rng, 6), max_gen=3,
+                    arrival_step=2)]
+    res = paged_nopfx.serve(reqs, policy=ServePolicy(max_slots=2))
+    m, pg = res["metrics"], res["metrics"]["paging"]
+    assert m["status_counts"] == {"ok": 2}
+    # the first token of each request comes from its prefill; each later
+    # one from a decode step at length prompt + tokens so far:
+    #   step 1: A at 6                2 blocks
+    #   step 2: A at 7                2
+    #   step 3: A at 8, B (admitted) at 7   2 + 2
+    #   step 4: B at 8 (A finished)   2
+    assert m["decode_steps"] == 4
+    assert pg["decode_blocks_live"] == 2 + 2 + (2 + 2) + 2
+    nb = paged_nopfx._paged_state["nb_max"]
+    assert pg["decode_blocks_visited"] == 4 * 2 * nb

@@ -80,13 +80,20 @@ def test_decode_attention_compiles(one_chip):
 
 # paged pool [NB+1, KV, bs, hd]: 4 rows x 17 blocks of 16 tokens, + trash
 POOL = ((4 * 17 + 1, H, 16, D), jnp.bfloat16)
+# chatglm3-6b: 32 query heads of 128 over 2 K/V groups, 128-token blocks
+GQA_H, GQA_POOL = 32, ((4 * 17 + 1, 2, 128, 128), jnp.bfloat16)
+# stablelm-1.6b at 128-token blocks: a tile too wide for one grid step
+MHA_WIDE_POOL = ((4 * 17 + 1, H, 128, D), jnp.bfloat16)
 
 
-def test_paged_decode_attention_compiles(one_chip):
+@pytest.mark.parametrize("heads,pool", [(H, POOL), (GQA_H, GQA_POOL),
+                                        (H, MHA_WIDE_POOL)],
+                         ids=["mha", "gqa", "mha_wide"])
+def test_paged_decode_attention_compiles(one_chip, heads, pool):
     _compile(lambda q, kp, vp, t, n: ops.paged_decode_attention(
                  q, kp, vp, t, n, interpret=False),
-             one_chip, ((4, 1, H, D), jnp.bfloat16), POOL, POOL,
-             ((4, 17), jnp.int32), ((4,), jnp.int32))
+             one_chip, ((4, 1, heads, pool[0][-1]), jnp.bfloat16), pool,
+             pool, ((4, 17), jnp.int32), ((4,), jnp.int32))
 
 
 def test_paged_prefill_attention_compiles(one_chip):
