@@ -10,11 +10,11 @@ Training, by the worst leaf of the program's layout:
                   under GRAD_FLOOR of the median leaf's (round-off moves
                   them, as it moves a key bias under softmax)
 Serving:
-  served_logit_gap  the widest gap, over the served tokens of the sampled
-                    requests, by which the reference's logit of a served
-                    token lies below the reference's best at that position
-                    (the reference in the configuration's own precision)
-  served_gap_mean   the mean of the same gaps over the served tokens
+  served_gap_mean   the mean, over the served tokens of the sampled
+                    requests, of the gap by which the reference's logit of
+                    a served token lies below the reference's best at that
+                    position (the reference in the configuration's own
+                    precision)
 A limit is set from readings (PERF.md lists them); a limit of None fails.
 """
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Reduction of a profiler trace (``.xplane.pb``) to device busy time,
-per-op device time, idle gaps and exposed collective time.
+per-op device time, idle gaps and a device's exposed collective time.
 
 The reading (``load``) and the arithmetic (``union``, ``reduce_events``,
 ...) are apart, so the arithmetic is tested on plain intervals. Times are
@@ -22,8 +22,10 @@ OP_LINES = ("XLA Ops",)
 # ops that only hold other ops (a loop over layers): their events span
 # their bodies' events, so they count toward busy time but not per op
 CONTAINERS = ("while", "conditional", "call")
-COLLECTIVE_MARKS = ("all-reduce", "all-gather", "collective-permute",
-                    "reduce-scatter", "all-to-all", "send", "recv")
+# collective ops, by the start of their HLO op (``collective-permute-start``
+# and ``-done`` are both the permute's)
+COLLECTIVES = ("collective-permute", "all-reduce", "all-gather",
+               "reduce-scatter", "all-to-all")
 HARNESS_SPANS = ("window", "setup", "feed", "step", "serve.call")
 
 
@@ -140,9 +142,11 @@ def gaps(busy: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
     return [(s, e) for s, e in out if e > s]
 
 
-def is_collective(ev: Event) -> bool:
-    low = ev.text.lower()
-    return any(m in low for m in COLLECTIVE_MARKS)
+def collective_kind(ev: Event) -> Optional[str]:
+    """The collective an op event runs (one of ``COLLECTIVES``), by its HLO
+    op or, where the event holds none, its name; else None."""
+    op = ev.kind or ev.name
+    return next((c for c in COLLECTIVES if op.startswith(c)), None)
 
 
 def exposed(collectives: Iterable[Interval], compute: Sequence[Interval]
@@ -155,14 +159,42 @@ def exposed(collectives: Iterable[Interval], compute: Sequence[Interval]
     return t
 
 
+def exposed_collectives(events: Iterable[Event], lo: float, hi: float
+                        ) -> Tuple[float, Dict[str, float]]:
+    """Of one device's op events, the time in [lo, hi] when a collective op
+    runs and no other op does (the union of the collective intervals less
+    the union of the others; a container's event spans its body and is
+    left out), and the time of each collective kind's events."""
+    coll, comp, by = [], [], {}
+    for ev in events:
+        kind = collective_kind(ev)
+        if kind is not None:
+            coll.append((ev.start, ev.end))
+            by[kind] = by.get(kind, 0.0) + total(
+                clip([(ev.start, ev.end)], lo, hi))
+        elif ev.kind not in CONTAINERS:
+            comp.append((ev.start, ev.end))
+    return exposed(clip(coll, lo, hi), clip(comp, lo, hi)), by
+
+
+def first_chip(planes: Iterable[str]) -> str:
+    """The plane of device 0: the lowest number ending a plane's name."""
+    def number(name):
+        m = re.search(r"(\d+)$", name)
+        return int(m.group(1)) if m else 0
+    return min(planes, key=number)
+
+
 @dataclasses.dataclass
 class Reduced:
     window_s: float
     busy_s: float                       # mean over device planes
     op_s: Dict[str, float]              # op name -> device seconds (summed)
     idle_gaps: List[Tuple[str, float]]  # (span open in the gap, seconds)
-    exposed_collective_s: float         # mean over device planes
     events: List[Event]                 # every op event in the window
+    planes: Dict[str, List[Event]] = dataclasses.field(
+        default_factory=dict)           # the same, by device plane
+    span: Interval = (0.0, 0.0)         # the window [lo, hi] in ns
 
 
 def _span_at(spans: Sequence[Event], t: float) -> str:
@@ -175,12 +207,13 @@ def _span_at(spans: Sequence[Event], t: float) -> str:
 
 def reduce_events(devices: Dict[str, List[Event]], spans: List[Event],
                   lo: float, hi: float, top: int = 10) -> Reduced:
-    """Busy share, per-op time, idle gaps and exposed collectives over the
-    window [lo, hi] (ns). Several device planes are averaged."""
-    busy, exp, ops, all_gaps, evs = [], [], {}, [], []
-    for evs_d in devices.values():
+    """Busy share, per-op time and idle gaps over the window [lo, hi]
+    (ns). Several device planes are averaged."""
+    busy, ops, all_gaps, evs, planes = [], {}, [], [], {}
+    for plane, evs_d in devices.items():
         inside = [e for e in evs_d if e.end > lo and e.start < hi]
         evs += inside
+        planes[plane] = inside
         iv = union(clip([(e.start, e.end) for e in inside], lo, hi))
         busy.append(total(iv))
         for e in inside:
@@ -188,14 +221,11 @@ def reduce_events(devices: Dict[str, List[Event]], spans: List[Event],
                 ops[e.label] = ops.get(e.label, 0.0) + e.dur / 1e9
         for s, e in gaps(iv, lo, hi):
             all_gaps.append((_span_at(spans, (s + e) / 2), (e - s) / 1e9))
-        coll = [(e.start, e.end) for e in inside if is_collective(e)]
-        comp = [(e.start, e.end) for e in inside if not is_collective(e)]
-        exp.append(exposed(clip(coll, lo, hi), comp))
     n = max(len(devices), 1)
     all_gaps.sort(key=lambda g: -g[1])
     return Reduced(window_s=(hi - lo) / 1e9, busy_s=sum(busy) / n / 1e9,
-                   op_s=ops, idle_gaps=all_gaps[:top],
-                   exposed_collective_s=sum(exp) / n / 1e9, events=evs)
+                   op_s=ops, idle_gaps=all_gaps[:top], events=evs,
+                   planes=planes, span=(lo, hi))
 
 
 def window_of(spans: Sequence[Event], name: str) -> Optional[Interval]:

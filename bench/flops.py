@@ -1,51 +1,11 @@
-"""Operations and bytes that the algorithm needs, computed from shapes.
+"""Operations and bytes of the kernels, computed from their shapes, and the
+least time of work at the chip's peaks.
 
-``m`` is a configuration's ``model`` section (bench/configs/*.json). Only
-the work the model requires counts: recomputation (remat, the flash
-backward's second pass over the scores) does not count toward model
-FLOPs, and the embedding lookup is no matrix product.
+The counts of a whole model (FLOPs per token, weight and K/V bytes) depend
+on its architecture and come from its family (bench/families/, through
+``harness.family``); what is here takes shapes or per-token bytes.
 """
 from __future__ import annotations
-
-
-def layer_matmul_params(m: dict) -> int:
-    d, hd = m["d_model"], m["head_dim"]
-    q, kv = m["heads"] * hd, m["kv_heads"] * hd
-    attn = d * q + 2 * d * kv + q * d
-    mlp = 3 * d * m["d_ff"]                       # SwiGLU: w1, w3, w2
-    return attn + mlp
-
-
-def matmul_params(m: dict) -> int:
-    """Matrix-product parameters: every layer plus the LM head."""
-    return m["layers"] * layer_matmul_params(m) + m["d_model"] * m["vocab"]
-
-
-def param_count(m: dict) -> int:
-    """All parameters: matrices, embedding, norms and biases."""
-    d, hd = m["d_model"], m["head_dim"]
-    norm = 2 * d if m["norm"] == "layernorm" else d
-    bias = (m["heads"] + 2 * m["kv_heads"]) * hd if m["qkv_bias"] else 0
-    per_layer = layer_matmul_params(m) + 2 * norm + bias
-    return (m["layers"] * per_layer + norm + 2 * m["vocab"] * d)
-
-
-def attn_flops_fwd(m: dict, q_len: int, kv_len: int, causal: bool) -> float:
-    """QK^T and PV of one sequence over every layer and head. Causal
-    attention of a block of queries at the end of ``kv_len`` keys counts
-    only the keys each query sees."""
-    hd, h = m["head_dim"], m["heads"]
-    if causal:
-        seen = q_len * kv_len - q_len * (q_len - 1) / 2
-    else:
-        seen = q_len * kv_len
-    return 4.0 * seen * hd * h * m["layers"]
-
-
-def train_flops_per_token(m: dict, seq: int) -> float:
-    """Forward and backward (x3) of the matmuls and of causal attention."""
-    return 6.0 * matmul_params(m) + 3.0 * attn_flops_fwd(
-        m, seq, seq, True) / seq
 
 
 # -- flash attention kernels (one call = one layer of one batch) -----------
@@ -82,28 +42,17 @@ def least_time(flops: float, nbytes: float, peaks: dict) -> tuple:
 
 # -- serving ------------------------------------------------------------------
 
-def kv_bytes_per_token(m: dict, itemsize: int = 2) -> int:
-    """K and V of one token over every layer."""
-    return 2 * m["layers"] * m["kv_heads"] * m["head_dim"] * itemsize
-
-
-def weight_bytes(m: dict, itemsize: int = 2) -> int:
-    """Bytes of the weights a forward step reads (embedding rows aside)."""
-    return (param_count(m) - m["vocab"] * m["d_model"]) * itemsize
-
-
-def paged_decode_bytes(m: dict, ctx_lens, block: int, itemsize: int = 2
-                       ) -> float:
+def paged_decode_bytes(kv_per_token: int, ctx_lens, block: int) -> float:
     """K and V bytes of the live blocks one paged decode reads over every
-    layer, for rows at context lengths ``ctx_lens``."""
+    layer, for rows at context lengths ``ctx_lens``; ``kv_per_token`` is
+    the family's ``kv_bytes_per_token``."""
     blocks = sum(-(-int(n) // block) for n in ctx_lens)
-    return blocks * block * kv_bytes_per_token(m, itemsize)
+    return blocks * block * kv_per_token
 
 
-def emitted_kv_bytes(m: dict, rows, block: int, itemsize: int = 2
-                     ) -> float:
+def emitted_kv_bytes(kv_per_token: int, rows, block: int) -> float:
     """K and V bytes the decode steps needed for the tokens the requests
     emitted. ``rows`` are (prompt length, tokens emitted): the first token
     comes from prefill; token i >= 1 is decoded at context prompt + i."""
     lens = [p + i for p, g in rows for i in range(1, g)]
-    return paged_decode_bytes(m, lens, block, itemsize)
+    return paged_decode_bytes(kv_per_token, lens, block)

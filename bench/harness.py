@@ -1,11 +1,19 @@
 """What every cell shares: the manifest, the device check, the weights and
 data the benchmark hands the program, compile counting, and the result.
 
-Cells, configurations, mixes and per-layer metrics are found by name:
-``BENCHMARK.json`` names them, ``bench/configs/<config>.json`` holds a
-configuration, ``bench/traffic/<mix>.json`` a mix (its ``driver`` picks
-bench/train_driver.py or bench/serve_driver.py), and
-``bench/metrics/<metric>.py`` the reader of one per-layer metric.
+Cells, configurations, architectures, mixes and per-layer metrics are
+found by name: ``BENCHMARK.json`` names them, ``bench/configs/<config>.json``
+holds a configuration, whose ``model`` section names its ``family``,
+``bench/families/<family>.py`` answers for that architecture (the program's
+config and its check, the plain reference, the counts of FLOPs and bytes),
+``bench/traffic/<mix>.json`` holds a mix, whose ``driver`` names
+``bench/<driver>_driver.py``, and ``bench/metrics/<metric>.py`` is the
+reader of one per-layer metric.
+
+So a new architecture is new files and entries only: a family module, a
+configuration file, a mix where it needs one, and its entries in
+``BENCHMARK.json``; no file of the harness changes
+(bench/tests/test_bench_families.py builds one).
 """
 from __future__ import annotations
 
@@ -14,6 +22,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 import sys
 import time
 from typing import List
@@ -68,14 +77,53 @@ def metrics_for(manifest: dict, workload: str, kind: str) -> List[dict]:
     return out
 
 
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
+
+
+def _module(kind: str, name: str, path: str):
+    """The module at ``path``, loaded once, or refuse."""
+    if not NAME.fullmatch(str(name)) or not os.path.exists(path):
+        raise Refused(f"no {kind} {name!r}: "
+                      f"{os.path.relpath(path, ROOT)} is not there")
+    key = f"bench_{kind}_" + re.sub(r"[^A-Za-z0-9_]", "_", name)
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]
+
+
 def metric_reader(name: str):
     """``read(ctx)`` of bench/metrics/<name>.py."""
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metric", name,
+                   os.path.join(HERE, "metrics", f"{name}.py")).read
+
+
+def family(m: dict):
+    """The module of bench/families/<family>.py that answers for the
+    architecture of a configuration's ``model`` section."""
+    name = m.get("family")
+    return _module("family", name,
+                   os.path.join(HERE, "families", f"{name}.py"))
+
+
+def driver(name: str):
+    """The module bench/<name>_driver.py that runs a mix whose ``driver``
+    is ``name``, or refuse."""
+    if not NAME.fullmatch(str(name)) or not os.path.exists(
+            os.path.join(HERE, f"{name}_driver.py")):
+        raise Refused(f"no driver {name!r}: bench/{name}_driver.py is "
+                      f"not there")
+    return importlib.import_module(f"{name}_driver")
+
+
+def limits(conf: dict, mix: dict) -> dict:
+    """The limits a cell's numbers are held to: those under the mix's
+    driver and plan (``train.zero_cdp``) where the configuration has them,
+    else those under its driver."""
+    lim = conf["limits"]
+    return lim.get(f"{mix['driver']}.{mix.get('plan')}", lim[mix["driver"]])
 
 
 def program_path() -> None:
@@ -169,25 +217,10 @@ def supplied(params=None, batches=None):
 
 
 def program_config(m: dict):
-    """The program's ModelConfig for a configuration's ``model`` section.
-    Depth and the QKV bias are options of the program and are set from the
-    file; every width is checked against it (the file is the yardstick; a
-    program config that drifted from it is refused)."""
-    from repro.configs import get_config
-    cfg = get_config(m["program_arch"]).with_(num_layers=m["layers"],
-                                              qkv_bias=m["qkv_bias"])
-    got = {"d_model": cfg.d_model, "heads": cfg.num_heads,
-           "kv_heads": cfg.num_kv_heads, "head_dim": cfg.resolved_head_dim,
-           "d_ff": cfg.d_ff, "vocab": cfg.vocab_size, "norm": cfg.norm,
-           "dtype": cfg.dtype,
-           "rope_interleaved": cfg.rope_2d,
-           "rotary_dims": int(cfg.resolved_head_dim *
-                              cfg.partial_rotary_factor)}
-    bad = {k: (v, m[k]) for k, v in got.items() if v != m[k]}
-    if bad:
-        raise Refused(f"program config {m['program_arch']} differs from "
-                      f"the benchmark's (program, file): {bad}")
-    return cfg
+    """The program's ModelConfig for a configuration's ``model`` section,
+    as its family sets it and checks it against the file (the file is the
+    yardstick; a program config that drifted from it is refused)."""
+    return family(m).program_config(m)
 
 
 def nearest_rank(values, q: float) -> float:

@@ -11,12 +11,14 @@ together, bound by whichever peak takes longer. Rows that the kernels read but t
 (mid-prefill rows, free slots) are not counted, so this is a lower bound
 of the work."""
 import flops
+import harness
 
 
 def kv_read_bytes(m, counters):
     """K/V bytes the window's decode steps needed for the tokens emitted."""
     rows = [(len(r.prompt), len(r.tokens)) for r in counters["requests"]]
-    return flops.emitted_kv_bytes(m, rows, counters["paging"]["block_size"])
+    return flops.emitted_kv_bytes(harness.family(m).kv_bytes_per_token(m),
+                                  rows, counters["paging"]["block_size"])
 
 
 def read(ctx):
@@ -24,11 +26,12 @@ def read(ctx):
     c = res.get("counters", {})
     if "paging" not in c or ctx["trace"].busy_s <= 0:
         return None
-    w = flops.weight_bytes(m)
+    fam = harness.family(m)
+    w = fam.weight_bytes(m)
     decode = (c["decode_steps"] * w + kv_read_bytes(m, c)) / \
         pk["hbm_bytes_s"]
     pre_tok = c["paging"]["marginal_prefill_tokens"]
-    pre_flops = 2.0 * flops.matmul_params(m) * pre_tok
+    pre_flops = 2.0 * fam.matmul_params(m) * pre_tok
     prefill = max(pre_flops / pk["bf16_flops"],
                   c["prefill_calls"] * w / pk["hbm_bytes_s"])
     return 100.0 * (decode + prefill) / ctx["trace"].busy_s

@@ -5,6 +5,7 @@ of its events in the window. Rows that emit nothing are not counted, so
 this is a lower bound."""
 import devtrace
 import flops
+import harness
 
 MARKS = {"decode": ("paged_decode",)}
 
@@ -18,6 +19,7 @@ def read(ctx):
     if spent <= 0:
         return None
     rows = [(len(r.prompt), len(r.tokens)) for r in c["requests"]]
-    need = flops.emitted_kv_bytes(ctx["model"], rows,
-                                  c["paging"]["block_size"])
+    m = ctx["model"]
+    need = flops.emitted_kv_bytes(harness.family(m).kv_bytes_per_token(m),
+                                  rows, c["paging"]["block_size"])
     return 100.0 * (need / ctx["peaks"]["hbm_bytes_s"]) / spent

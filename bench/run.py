@@ -4,8 +4,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (BENCHMARK.json ``workloads``) names a configuration
-(bench/configs/<config>.json) and a mix (bench/traffic/<mix>.json, whose
-``driver`` is ``train`` or ``serve``). The run builds the program on
+(bench/configs/<config>.json, whose family answers for the architecture:
+bench/families/<family>.py) and a mix (bench/traffic/<mix>.json, whose
+``driver`` names bench/<driver>_driver.py). The run builds the program on
 weights and inputs made from ``--seed``, warms up every shape the window
 uses (set-up), measures for ``--seconds``, checks what the window produced
 against the plain reference, and prints one JSON line last on stdout:
@@ -94,13 +95,11 @@ def main(argv=None) -> int:
     harness.program_path()
     import traffic
     mix = traffic.load_mix(w["traffic"])
+    driver = harness.driver(mix["driver"])
+    harness.family(conf["model"])        # refused here, before the chip
     import jax
     harness.enable_cache()
     devs = harness.device_check(w["chips"])
-    if mix["driver"] == "train":
-        import train_driver as driver
-    else:
-        import serve_driver as driver
 
     tracer = None
     if args.trace:
@@ -112,7 +111,7 @@ def main(argv=None) -> int:
     out = driver.run(ctx)
     log(f"compilations inside the measured window: "
         f"{out['compiles_in_window']}")
-    limits = conf["limits"][mix["driver"]]
+    limits = harness.limits(conf, mix)
     import compare
     checks = compare.checks(out["readings"], limits)
     result = {"correct": all(c["ok"] for c in checks),

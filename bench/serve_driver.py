@@ -12,9 +12,10 @@ or counts as failed. Every emitted token is timed by the harness's own
 
 Correctness: a sample of the finished requests, drawn from the seed and
 holding the longest, is run through the plain reference teacher-forced
-(prompt + served tokens), in the configuration's own precision; the widest
+(prompt + served tokens), in the configuration's own precision; the mean
 gap by which a served token's reference logit lies below the reference's
-best is compared.
+best is compared. The widest gap is logged beside it, not compared: on a
+sound seed it reached a third of the float8 control's least (PERF.md).
 """
 from __future__ import annotations
 
@@ -25,7 +26,6 @@ import numpy as np
 
 import compare
 import harness
-import reference
 import traffic
 import weights
 from harness import log, now
@@ -179,23 +179,27 @@ def check_sample(prog, mix, seed):
 
 
 def readings(m, params, sample, control=None):
-    """served_logit_gap and served_gap_mean for the sample, against the
-    plain reference in the configuration's own precision. ``control``
-    names the precision of a reference put in the program's place: its
-    argmax is the served token at every position (it need not decode)."""
+    """served_gap_mean for the sample, against the plain reference in the
+    configuration's own precision; the widest gaps and the number of
+    tokens off the reference's best are logged. ``control`` names the
+    precision of a reference put in the program's place: its argmax is the
+    served token at every position (it need not decode)."""
     prec = REF_PREC[m["dtype"]]
+    ref = harness.family(m)
     if control is not None:
-        picked = reference.forward_served(m, params, sample["tokens"],
-                                          sample["pos"], control)
-        gaps = reference.served_gaps(m, params, sample["tokens"],
-                                     sample["pos"], sample["served"], prec,
-                                     picked=picked)
+        picked = ref.forward_served(m, params, sample["tokens"],
+                                    sample["pos"], control)
+        gaps = ref.served_gaps(m, params, sample["tokens"], sample["pos"],
+                               sample["served"], prec, picked=picked)
     else:
-        gaps = reference.served_gaps(m, params, sample["tokens"],
-                                     sample["pos"], sample["served"], prec)
+        gaps = ref.served_gaps(m, params, sample["tokens"], sample["pos"],
+                               sample["served"], prec)
     gaps = gaps[sample["valid"]]
-    return {"served_logit_gap": float(gaps.max()),
-            "served_gap_mean": float(gaps.mean())}
+    top = np.sort(gaps)[::-1][:5]
+    log(f"{control or 'program'} gaps: widest {float(top[0])!r}, "
+        f"{int((gaps > 0).sum())} of {gaps.size} tokens off the "
+        f"reference's best, largest {[float(g) for g in top]}")
+    return {"served_gap_mean": float(gaps.mean())}
 
 
 def run(ctx):
@@ -206,8 +210,7 @@ def run(ctx):
     sample = check_sample(prog, mix, seed)
     t_ref = now()
     rd = (readings(m, prog["params"], sample) if sample is not None
-          else {"served_logit_gap": float("nan"),
-                "served_gap_mean": float("nan")})
+          else {"served_gap_mean": float("nan")})
     log(f"reference: {now() - t_ref:.1f}s over "
         f"{0 if sample is None else int(sample['valid'].sum())} tokens")
     failed = sum(r.status != "ok" for r in prog["requests"])

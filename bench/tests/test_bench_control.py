@@ -23,13 +23,15 @@ SEED = 2 ** 31 + 99
 
 TINY = {
     "stablelm-1.6b": {
-        "program_arch": "stablelm-1.6b", "layers": 2, "d_model": 256,
+        "family": "dense", "program_arch": "stablelm-1.6b",
+        "layers": 2, "d_model": 256,
         "heads": 4, "kv_heads": 4, "head_dim": 64, "d_ff": 512,
         "vocab": 512, "norm": "layernorm", "norm_eps": 1e-5,
         "qkv_bias": True, "rotary_dims": 16, "rope_interleaved": False,
         "rope_theta": 10000.0, "dtype": "float32"},
     "chatglm3-6b": {
-        "program_arch": "chatglm3-6b", "layers": 2, "d_model": 256,
+        "family": "dense", "program_arch": "chatglm3-6b",
+        "layers": 2, "d_model": 256,
         "heads": 4, "kv_heads": 1, "head_dim": 64, "d_ff": 512,
         "vocab": 512, "norm": "rmsnorm", "norm_eps": 1e-5,
         "qkv_bias": True, "rotary_dims": 32, "rope_interleaved": True,

@@ -79,9 +79,15 @@ def test_files_found_by_name(manifest):
     for w in manifest["workloads"]:
         path = os.path.join(BENCH, "traffic", f"{w['traffic']}.json")
         with open(path) as f:
-            assert json.load(f)["driver"] in ("train", "serve")
+            driver = json.load(f)["driver"]
+        assert os.path.exists(os.path.join(BENCH, f"{driver}_driver.py"))
+        assert harness.family(conf_of(manifest, w)["model"])
     for m in manifest["per_layer"]:
         assert callable(harness.metric_reader(m["name"]))
+
+
+def conf_of(manifest, w):
+    return harness.load_config(manifest, w["config"])
 
 
 def test_config_model_sections_match_the_published_keys(manifest):

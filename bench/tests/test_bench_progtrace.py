@@ -79,7 +79,7 @@ def _ctx(prog, events, lo, hi, result):
     (the program trace already read, as ``progtrace.of`` keeps it)."""
     busy = tr.total(pt.busy(events, lo, hi))
     red = tr.Reduced(window_s=(hi - lo) / 1e9, busy_s=busy / 1e9, op_s={},
-                     idle_gaps=[], exposed_collective_s=0.0, events=events)
+                     idle_gaps=[], events=events)
     return {"progtrace": prog, "trace": red, "result": result}
 
 

@@ -50,7 +50,9 @@ def test_reduce_events_busy_idle_and_gap_labels():
     # chip 0 busy 0..60 and 100..200; chip 1 busy 0..100; mean 130 ns
     assert r.busy_s == pytest.approx(130e-9)
     assert r.op_s["fusion.1"] == pytest.approx(140e-9)
-    assert r.exposed_collective_s == pytest.approx(20e-9 / 2)
+    assert tr.first_chip(r.planes) == "/device:TPU:0"
+    bare, by = tr.exposed_collectives(r.planes["/device:TPU:0"], *r.span)
+    assert bare == 20 and by == {"all-reduce": 50}
     # the longest gap, 100..300 on chip 1, sits under "window" alone;
     # chip 0's 60..100 lies inside the "feed" span
     assert r.idle_gaps[0] == ("window", pytest.approx(200e-9))
